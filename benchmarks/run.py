@@ -20,6 +20,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from benchmarks.common import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         bench_convergence,
         bench_fault_tolerance,

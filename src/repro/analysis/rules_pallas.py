@@ -83,6 +83,9 @@ class PallasCallInfo:
     out_specs: List[SpecInfo]
     out_shapes: List[Tuple[Optional[List[Optional[float]]], Optional[str]]]
     scratch: List[SpecInfo]
+    # scalar-prefetch operands (``PrefetchScalarGridSpec``): they lead the
+    # kernel's refs and trail every index_map's grid indices
+    num_prefetch: int = 0
 
 
 def _dtype_tail(node: Optional[ast.AST]) -> Optional[str]:
@@ -155,8 +158,17 @@ def parse_pallas_calls(ctx: FileContext) -> List[PallasCallInfo]:
             continue
         fn = ctx.enclosing_function(call)
         env = local_assignments(fn) if fn is not None else {}
+        # grid / specs given directly, or bundled in grid_spec=GridSpec(...)
+        # / PrefetchScalarGridSpec(num_scalar_prefetch=n, ...)
+        src = call
+        num_prefetch = 0
+        grid_spec = deref(keyword_arg(call, "grid_spec"), env)
+        if isinstance(grid_spec, ast.Call):
+            src = grid_spec
+            n = keyword_arg(grid_spec, "num_scalar_prefetch")
+            num_prefetch = int(ctx.consts.fold(n) or 0) if n is not None else 0
 
-        grid_node = deref(keyword_arg(call, "grid"), env)
+        grid_node = deref(keyword_arg(src, "grid"), env)
         if isinstance(grid_node, (ast.Tuple, ast.List)):
             grid_vals = [ctx.consts.fold(el) for el in grid_node.elts]
         elif grid_node is not None:
@@ -166,7 +178,7 @@ def parse_pallas_calls(ctx: FileContext) -> List[PallasCallInfo]:
             grid_vals = None
 
         def spec_list(kw: str) -> List[SpecInfo]:
-            node = deref(keyword_arg(call, kw), env)
+            node = deref(keyword_arg(src, kw), env)
             if node is None:
                 return []
             elts = node.elts if isinstance(node, (ast.Tuple, ast.List)) else [node]
@@ -191,7 +203,7 @@ def parse_pallas_calls(ctx: FileContext) -> List[PallasCallInfo]:
         for spec, (_, dt) in zip(out_specs, out_shapes):
             spec.dtype = dt
 
-        scratch_node = deref(keyword_arg(call, "scratch_shapes"), env)
+        scratch_node = deref(keyword_arg(src, "scratch_shapes"), env)
         scratch = []
         if isinstance(scratch_node, (ast.Tuple, ast.List)):
             for el in scratch_node.elts:
@@ -208,6 +220,7 @@ def parse_pallas_calls(ctx: FileContext) -> List[PallasCallInfo]:
                 out_specs=out_specs,
                 out_shapes=out_shapes,
                 scratch=scratch,
+                num_prefetch=num_prefetch,
             )
         )
     return out
@@ -215,10 +228,12 @@ def parse_pallas_calls(ctx: FileContext) -> List[PallasCallInfo]:
 
 @register
 class IndexMapContract(Rule):
-    """PL01: index_map arity must equal the grid rank and its returned tuple
-    must have one component per block-shape dimension. An arity/rank drift —
-    the classic symptom of adding a grid axis without updating every spec —
-    compiles to wrong indexing or crashes at trace time deep in Mosaic."""
+    """PL01: index_map arity must equal the grid rank (plus one ref per
+    scalar-prefetch operand of a ``PrefetchScalarGridSpec``) and its returned
+    tuple must have one component per block-shape dimension. An arity/rank
+    drift — the classic symptom of adding a grid axis without updating every
+    spec — compiles to wrong indexing or crashes at trace time deep in
+    Mosaic."""
 
     id = "PL01"
     pack = "pallas"
@@ -231,14 +246,22 @@ class IndexMapContract(Rule):
                 for i, spec in enumerate(specs):
                     if spec.index_params is None:
                         continue
-                    if n_grid is not None and len(spec.index_params) != n_grid:
+                    if (
+                        n_grid is not None
+                        and len(spec.index_params) != n_grid + info.num_prefetch
+                    ):
+                        extra = (
+                            f" + {info.num_prefetch} scalar-prefetch refs"
+                            if info.num_prefetch
+                            else ""
+                        )
                         yield Finding(
                             self.id,
                             ctx.path,
                             spec.node.lineno,
                             f"{role}_specs[{i}] index_map takes "
                             f"{len(spec.index_params)} args but the grid has "
-                            f"{n_grid} axes",
+                            f"{n_grid} axes{extra}",
                         )
                     if spec.shape_vals is not None and spec.index_body is not None:
                         if len(spec.index_body) != len(spec.shape_vals):
@@ -326,8 +349,10 @@ class TileAlignment(Rule):
     Misaligned blocks force Mosaic to pad every VMEM tile (silent bandwidth
     loss) and some layouts are rejected outright on real TPUs. Inputs default
     to the f32 tile when their dtype is unknowable; outputs use the
-    ``out_shape`` dtype. Rank-0/1 blocks and unresolvable dims are skipped;
-    VMEM *scratch* is exempt (private, compiler-padded — PL04 budgets it)."""
+    ``out_shape`` dtype. Rank-0/1 blocks and unresolvable dims are skipped —
+    among them a dim written as the operand's own ``x.shape[d]``, which spans
+    the array and is the other layout Mosaic accepts; VMEM *scratch* is
+    exempt (private, compiler-padded — PL04 budgets it)."""
 
     id = "PL03"
     pack = "pallas"
@@ -430,7 +455,7 @@ class RevisitedAccumulation(Rule):
             if kernel is None:
                 continue
             pos_params = [a.arg for a in kernel.args.args]  # kw-only are static
-            n_in = len(info.in_specs)
+            n_in = info.num_prefetch + len(info.in_specs)
             guarded = _guarded_nodes(kernel)
             for i, spec in enumerate(info.out_specs):
                 if spec.index_params is None or spec.index_body is None:
@@ -441,7 +466,8 @@ class RevisitedAccumulation(Rule):
                     for n in ast.walk(comp)
                     if isinstance(n, ast.Name)
                 }
-                ignored = [p for p in spec.index_params if p not in used]
+                axes = spec.index_params[: len(spec.index_params) - info.num_prefetch]
+                ignored = [p for p in axes if p not in used]
                 if not ignored:
                     continue  # every grid axis moves the block: no revisit
                 if n_in + i >= len(pos_params):

@@ -21,6 +21,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ipls_aggregate.ipls_aggregate import default_interpret
+
 BS = 256
 NEG_INF = float(np.finfo(np.float32).min)
 
@@ -64,8 +66,10 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention(q, k, v, pos, interpret: bool = True):
+def decode_attention(q, k, v, pos, interpret: bool | None = None):
     """q: (B,H,D); k,v: (B,H,S,D), S % 256 == 0; pos: () int32."""
+    if interpret is None:
+        interpret = default_interpret()
     B, H, D = q.shape
     S = k.shape[2]
     assert S % BS == 0, (S,)

@@ -7,7 +7,7 @@ from repro.kernels.decode_attention.decode_attention import decode_attention
 from repro.kernels.decode_attention.ref import decode_ref
 
 
-def decode(q, k, v, pos, use_kernel: bool = True, interpret: bool = True):
+def decode(q, k, v, pos, use_kernel: bool = True, interpret: bool | None = None):
     """q: (B,H,D); k,v: (B,KV,S,D)."""
     H, KV = q.shape[1], k.shape[1]
     if KV != H:

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ipls_aggregate.ipls_aggregate import default_interpret
+
 BQ = 128
 BK = 128
 NEG_INF = float(np.finfo(np.float32).min)
@@ -71,8 +73,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, causal, scale,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = True):
+def flash_attention(q, k, v, causal: bool = True, interpret: bool | None = None):
     """q,k,v: (B, H, S, D) with S % 128 == 0. Returns (B, H, S, D)."""
+    if interpret is None:
+        interpret = default_interpret()
     B, H, S, D = q.shape
     assert S % BQ == 0 and S % BK == 0, (S,)
     scale = 1.0 / np.sqrt(D)

@@ -7,7 +7,7 @@ from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import mha_ref
 
 
-def attention(q, k, v, causal: bool = True, use_kernel: bool = True, interpret: bool = True):
+def attention(q, k, v, causal: bool = True, use_kernel: bool = True, interpret: bool | None = None):
     """q: (B,H,S,D); k,v: (B,KV,S,D) with H % KV == 0 (repeated here)."""
     H, KV = q.shape[1], k.shape[1]
     if KV != H:

@@ -50,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BR = 256  # tile rows; lanes fixed at 128
 BR_BATCHED = 128  # smaller tile for the partition-batched grid (less padding)
@@ -113,21 +114,21 @@ def ipls_aggregate(w, deltas, mask, eps, interpret: bool | None = None):
     return out.reshape(-1)[:N]
 
 
-def _kernel_batched(table_ref, w_ref, deltas_ref, out_ref):
-    # table_ref: (1, Rp+1) per-partition [mask(Rp), eps]; Rp is the
-    # R_TILE-padded contributor count. w_ref: (1, BR_BATCHED, 128);
-    # deltas_ref: (1, R_TILE, BR_BATCHED, 128) — one R-chunk per grid step.
+def _kernel_batched(table_ref, w_ref, deltas_ref, out_ref, *, rp):
+    # table_ref: SMEM (K*(rp+1),) scalar-prefetched flat table, partition
+    # k's row [mask(rp), eps] at offset k*(rp+1); rp is the R_TILE-padded
+    # contributor count. w_ref: (1, BR_BATCHED, 128); deltas_ref:
+    # (1, R_TILE, BR_BATCHED, 128) — one R-chunk per grid step.
     # The grid's last axis walks the R-chunks sequentially; the running sum
     # is carried through the revisited output block so the reduction order
     # is strictly slot 0,1,2,... — bit-identical to the scalar oracle's
     # sequential np.sum (masked-out slots add an exact +0.0).
+    k = pl.program_id(0)
     rt = pl.program_id(2)
     n_rt = pl.num_programs(2)
-    me = table_ref[0]
-    Rp = me.shape[0] - 1
     RT = deltas_ref.shape[1]
-    mask_blk = jax.lax.dynamic_slice(me, (rt * RT,), (RT,))
-    eps = me[Rp]
+    row = k * (rp + 1)
+    eps = table_ref[row + rp]
 
     @pl.when(rt == 0)
     def _():
@@ -135,7 +136,7 @@ def _kernel_batched(table_ref, w_ref, deltas_ref, out_ref):
 
     acc = out_ref[0].astype(jnp.float32)
     for r in range(RT):  # static unroll of one chunk
-        acc = acc + mask_blk[r] * deltas_ref[0, r].astype(jnp.float32)
+        acc = acc + table_ref[row + rt * RT + r] * deltas_ref[0, r].astype(jnp.float32)
 
     @pl.when(rt < n_rt - 1)
     def _():
@@ -159,7 +160,9 @@ def ipls_aggregate_batched(w, deltas, mask, eps, interpret: bool | None = None):
     contributor tables neither unroll into huge kernel bodies nor exceed
     VMEM. Partitions of unequal true size share the padded N; callers
     zero-pad tails (the padded lanes compute garbage-free zeros since
-    pad(w)=pad(deltas)=0).
+    pad(w)=pad(deltas)=0). The ``[mask, eps]`` table is scalar-prefetched
+    into SMEM (K*(Rp+1) f32 words; SMEM holds 1 MiB on v5e) and read as
+    scalars, so no table block has to meet the (8, 128) tiling rule.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -175,21 +178,24 @@ def ipls_aggregate_batched(w, deltas, mask, eps, interpret: bool | None = None):
     w3 = wp.reshape(K, rows, LANES)
     d4 = dp.reshape(K, Rp, rows, LANES)
     mask_f = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, rpad)))
-    table = jnp.concatenate([mask_f, eps.astype(jnp.float32)[:, None]], axis=1)  # (K, Rp+1)
-    grid = (K, rows // BR_BATCHED, Rp // R_TILE)
+    table = jnp.concatenate([mask_f, eps.astype(jnp.float32)[:, None]], axis=1)
 
     out = pl.pallas_call(
-        _kernel_batched,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Rp + 1), lambda k, i, rt: (k, 0)),
-            pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt: (k, i, 0)),
-            pl.BlockSpec((1, R_TILE, BR_BATCHED, LANES), lambda k, i, rt: (k, rt, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt: (k, i, 0)),
+        functools.partial(_kernel_batched, rp=Rp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(K, rows // BR_BATCHED, Rp // R_TILE),
+            in_specs=[
+                pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, i, 0)),
+                pl.BlockSpec(
+                    (1, R_TILE, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, rt, i, 0)
+                ),
+            ],
+            out_specs=pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, i, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((K, rows, LANES), w.dtype),
         interpret=interpret,
-    )(table, w3, d4)
+    )(table.reshape(-1), w3, d4)
     return out.reshape(K, -1)[:, :N]
 
 
@@ -198,7 +204,7 @@ def ipls_aggregate_batched(w, deltas, mask, eps, interpret: bool | None = None):
 SB_TILE = BR_BATCHED * LANES // QBLOCK
 
 
-def _kernel_batched_q(table_ref, w_ref, own_ref, q_ref, s_ref, out_ref):
+def _kernel_batched_q(table_ref, w_ref, own_ref, q_ref, s_ref, out_ref, *, rp):
     # Quantized contributor rows: deltas arrive as int8 codes q plus per-
     # QBLOCK f32 scales; dequantize (q * scale — exact, scales are powers of
     # two or 0) fuses into the masked-sum accumulation, so the f32 deltas
@@ -206,19 +212,20 @@ def _kernel_batched_q(table_ref, w_ref, own_ref, q_ref, s_ref, out_ref):
     # and stays raw f32 (own_ref), gated by the own_mask table slot and
     # summed FIRST — the scalar oracle pushes the local delta into pending
     # before draining the inbox, and sum order must match bit for bit.
-    # table_ref: (1, Rp+2) = [mask(Rp), own_mask, eps];
+    # table_ref: SMEM (K*(rp+2),), row k = [mask(rp), own_mask, eps];
     # q_ref: (1, R_TILE, BR_BATCHED, 128) int8;
-    # s_ref: (1, R_TILE, SB_TILE) f32 — SB_TILE scale blocks per row-tile.
+    # s_ref: (1, 1, SB_TILE, R_TILE) f32 — the row-tile's SB_TILE scale
+    # blocks (sublanes) for the chunk's R_TILE contributors (lanes).
+    k = pl.program_id(0)
     rt = pl.program_id(2)
     n_rt = pl.num_programs(2)
-    me = table_ref[0]
-    Rp = me.shape[0] - 2
     RT = q_ref.shape[1]
-    mask_blk = jax.lax.dynamic_slice(me, (rt * RT,), (RT,))
-    own_mask = me[Rp]
-    eps = me[Rp + 1]
+    row = k * (rp + 2)
+    own_mask = table_ref[row + rp]
+    eps = table_ref[row + rp + 1]
     rows = w_ref.shape[1]
     rows_per_block = QBLOCK // LANES  # 8 contiguous lane-rows share a scale
+    s_blk = s_ref[0, 0]  # (SB_TILE, R_TILE)
 
     @pl.when(rt == 0)
     def _():
@@ -226,11 +233,12 @@ def _kernel_batched_q(table_ref, w_ref, own_ref, q_ref, s_ref, out_ref):
 
     acc = out_ref[0].astype(jnp.float32)
     for r in range(RT):  # static unroll of one chunk
-        s_rows = s_ref[0, r]  # (SB_TILE,)
+        s_col = s_blk[:, r : r + 1]  # (SB_TILE, 1)
         s_full = jnp.broadcast_to(
-            s_rows[:, None, None], (SB_TILE, rows_per_block, 1)
+            s_col[:, None, :], (SB_TILE, rows_per_block, 1)
         ).reshape(rows, 1)
-        acc = acc + mask_blk[r] * (q_ref[0, r].astype(jnp.float32) * s_full)
+        m = table_ref[row + rt * RT + r]
+        acc = acc + m * (q_ref[0, r].astype(jnp.float32) * s_full)
 
     @pl.when(rt < n_rt - 1)
     def _():
@@ -255,7 +263,10 @@ def ipls_aggregate_batched_q(
     ``w - eps * (own_mask*own + sum_r mask[r]*deq(q[r]))`` with
     deq(q) = q * scale fused into the R_TILE accumulation, own summed first.
     Zero-contributor rows (own_mask and mask all zero) pass through
-    unchanged.
+    unchanged. The ``[mask, own_mask, eps]`` table is scalar-prefetched into
+    SMEM; the scales are laid out (K, Rp/R_TILE, blocks, R_TILE) so each
+    grid step reads one (SB_TILE, R_TILE) block whose last dim spans the
+    array's.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -276,26 +287,31 @@ def ipls_aggregate_batched_q(
     w3 = wp.reshape(K, rows, LANES)
     o3 = op.reshape(K, rows, LANES)
     q4 = qp.reshape(K, Rp, rows, LANES)
+    s4 = sp.reshape(K, Rp // R_TILE, R_TILE, nbp).transpose(0, 1, 3, 2)
     mask_f = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, rpad)))
     own_f = own_mask.astype(jnp.float32)[:, None]
-    table = jnp.concatenate(
-        [mask_f, own_f, eps.astype(jnp.float32)[:, None]], axis=1
-    )  # (K, Rp+2)
-    grid = (K, rows // BR_BATCHED, Rp // R_TILE)
+    table = jnp.concatenate([mask_f, own_f, eps.astype(jnp.float32)[:, None]], axis=1)
 
     out = pl.pallas_call(
-        _kernel_batched_q,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Rp + 2), lambda k, i, rt: (k, 0)),
-            pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt: (k, i, 0)),
-            pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt: (k, i, 0)),
-            pl.BlockSpec((1, R_TILE, BR_BATCHED, LANES), lambda k, i, rt: (k, rt, i, 0)),
-            # repro: noqa[PL03] per-block scales: SB_TILE=16 scalars per row-tile
-            pl.BlockSpec((1, R_TILE, SB_TILE), lambda k, i, rt: (k, rt, i)),
-        ],
-        out_specs=pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt: (k, i, 0)),
+        functools.partial(_kernel_batched_q, rp=Rp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(K, rows // BR_BATCHED, Rp // R_TILE),
+            in_specs=[
+                pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, i, 0)),
+                pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, i, 0)),
+                pl.BlockSpec(
+                    (1, R_TILE, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, rt, i, 0)
+                ),
+                # lanes: all R_TILE contributors of the chunk, the array's
+                # whole last dim
+                pl.BlockSpec(
+                    (1, 1, SB_TILE, s4.shape[3]), lambda k, i, rt, tbl: (k, rt, i, 0)
+                ),
+            ],
+            out_specs=pl.BlockSpec((1, BR_BATCHED, LANES), lambda k, i, rt, tbl: (k, i, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((K, rows, LANES), w.dtype),
         interpret=interpret,
-    )(table, w3, o3, q4, sp)
+    )(table.reshape(-1), w3, o3, q4, s4)
     return out.reshape(K, -1)[:, :N]

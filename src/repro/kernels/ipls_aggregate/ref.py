@@ -20,7 +20,9 @@ def ipls_aggregate_ref(
 ) -> jax.Array:
     """w - eps * masked_sum(deltas); empty mask leaves w unchanged."""
     mask = mask.astype(jnp.float32)
-    agg = jnp.einsum("r,rn->n", mask, deltas.astype(jnp.float32))
+    agg = jnp.einsum(
+        "r,rn->n", mask, deltas.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )
     return (w.astype(jnp.float32) - eps.astype(jnp.float32) * agg).astype(w.dtype)
 
 
@@ -35,7 +37,9 @@ def ipls_aggregate_batched_ref(
     partition unchanged. R is whatever the round's contributor table needs —
     the kernel pads it to R_TILE chunks, the oracle takes it as-is."""
     mask = mask.astype(jnp.float32)
-    agg = jnp.einsum("kr,krn->kn", mask, deltas.astype(jnp.float32))
+    agg = jnp.einsum(
+        "kr,krn->kn", mask, deltas.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )
     return (w.astype(jnp.float32) - eps.astype(jnp.float32)[:, None] * agg).astype(w.dtype)
 
 
@@ -60,5 +64,7 @@ def ipls_aggregate_batched_q_ref(
     deq = deq.reshape(K, R, nb * qblock)[..., :N]
     mask = mask.astype(jnp.float32)
     own_mask = own_mask.astype(jnp.float32)
-    agg = own_mask[:, None] * own.astype(jnp.float32) + jnp.einsum("kr,krn->kn", mask, deq)
+    agg = own_mask[:, None] * own.astype(jnp.float32) + jnp.einsum(
+        "kr,krn->kn", mask, deq, precision=jax.lax.Precision.HIGHEST
+    )
     return (w.astype(jnp.float32) - eps.astype(jnp.float32)[:, None] * agg).astype(w.dtype)

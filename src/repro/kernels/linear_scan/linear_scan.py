@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ipls_aggregate.ipls_aggregate import default_interpret
+
 Q = 64  # chunk length
 
 
@@ -74,9 +76,11 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, sfin_ref, s_scr, *, nc):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rwkv6_scan(r, k, v, logw, u, interpret: bool = True):
+def rwkv6_scan(r, k, v, logw, u, interpret: bool | None = None):
     """r,k,v,logw: (B,T,H,K) with T % 64 == 0; u: (H,K).
     Returns (out (B,T,H,K), final_state (B,H,K,V))."""
+    if interpret is None:
+        interpret = default_interpret()
     B, T, H, K = r.shape
     V = v.shape[-1]
     assert T % Q == 0, (T,)

@@ -9,18 +9,33 @@ from __future__ import annotations
 
 
 import jax
+from jax.sharding import AxisType, Mesh
+
+
+def _auto(n: int) -> tuple:
+    # jax.make_mesh defaults to Explicit axes; the launcher's shardings are
+    # with_sharding_constraint / in_shardings rules, which need Auto axes
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names — smoke tests exercise the
     same sharding code paths without fake devices."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))
+
+
+def make_data_mesh(n: int) -> Mesh:
+    """("data"=n, "model"=1) mesh over the first n devices: the IPLS agent
+    axis spread over n chips of one host."""
+    return jax.make_mesh(
+        (n, 1), ("data", "model"), axis_types=_auto(2), devices=jax.devices()[:n]
+    )
 
 
 def dp_axes(mesh) -> tuple:

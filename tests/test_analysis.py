@@ -28,10 +28,12 @@ REPO = Path(__file__).resolve().parents[1]
 # fixture file -> the one rule it must fire (and nothing else may fire)
 FIRE_CASES = {
     "pl01_fire.py": "PL01",
+    "pl01_prefetch_fire.py": "PL01",
     "pl02_fire.py": "PL02",
     "pl03_fire.py": "PL03",
     "pl04_fire.py": "PL04",
     "pl05_fire.py": "PL05",
+    "pl05_prefetch_fire.py": "PL05",
     "jx01_fire.py": "JX01",
     "jx02_fire.py": "JX02",
     "jx03_fire.py": "JX03",
