@@ -1,0 +1,6 @@
+"""Device time of the `eval` layer per round (see _fleet_layers.py)."""
+from benchmarks.chip.metrics import _fleet_layers
+
+
+def read(ctx):
+    return _fleet_layers.ms_per_round(ctx, "eval")
