@@ -464,10 +464,11 @@ class VectorizedIPLSSimulation:
             replica that served their UpdateModel reply. One concatenate =
             one output pass (a dynamic_update_slice chain copies the whole
             (rows, N) buffer K times on the CPU backend)."""
-            V_all = jnp.concatenate([V_pre, V_merged], axis=0)
-            return jnp.concatenate(
-                [V_all[t_inst[:, k], : sizes[k]] for k in range(K)], axis=1
-            )
+            with jax.named_scope("weight_build"):
+                V_all = jnp.concatenate([V_pre, V_merged], axis=0)
+                return jnp.concatenate(
+                    [V_all[t_inst[:, k], : sizes[k]] for k in range(K)], axis=1
+                )
 
         # instance rows are k-major, so each partition's instances form a
         # contiguous row range of the (K_inst, A) contribution matrix
@@ -499,11 +500,12 @@ class VectorizedIPLSSimulation:
                 # ONE kernel launch. The kernel computes w - eps*masked_sum,
                 # exactly the scalar engine's update (the 1/r lives in the
                 # eps recursion).
-                lane = jnp.arange(S, dtype=jnp.int32)
-                valid = lane[None, :] < size_inst[:, None]   # (K_inst, S)
-                col = jnp.where(valid, off_inst[:, None] + lane[None, :], 0)
-                G = D[contrib_idx[:, :, None], col[:, None, :]]
-                G = G * valid[:, None, :]
+                with jax.named_scope("contrib_gather"):
+                    lane = jnp.arange(S, dtype=jnp.int32)
+                    valid = lane[None, :] < size_inst[:, None]   # (K_inst, S)
+                    col = jnp.where(valid, off_inst[:, None] + lane[None, :], 0)
+                    G = D[contrib_idx[:, :, None], col[:, None, :]]
+                    G = G * valid[:, None, :]
                 V_pre = aggregate_batched(base, G, contrib_mask, eps_new)
             else:
                 # CPU/GPU: per-partition static column slice + whole-row
@@ -534,15 +536,16 @@ class VectorizedIPLSSimulation:
             # replica consensus: each instance averages [self] + the other
             # replicas in arrival (holder agent ascending) order — the
             # scalar engine's np.mean associates exactly this way
-            acc = V_pre
-            for j in range(1, max_rho):
-                acc = jnp.where(
-                    mmask_m[:, j, None] > 0, acc + V_pre[morder[:, j]], acc
-                )
-            # barrier the (constant) divisor too: XLA folds division by a
-            # constant into multiply-by-reciprocal, off by an ULP for
-            # rho=3 — scalar np.mean does a true divide
-            V_merged_new = acc / jax.lax.optimization_barrier(rho_inst)[:, None]
+            with jax.named_scope("replica_merge"):
+                acc = V_pre
+                for j in range(1, max_rho):
+                    acc = jnp.where(
+                        mmask_m[:, j, None] > 0, acc + V_pre[morder[:, j]], acc
+                    )
+                # barrier the (constant) divisor too: XLA folds division by a
+                # constant into multiply-by-reciprocal, off by an ULP for
+                # rho=3 — scalar np.mean does a true divide
+                V_merged_new = acc / jax.lax.optimization_barrier(rho_inst)[:, None]
             return V_pre, V_merged_new, eps_new
 
         def eval_rows(V_pre, V_merged_new, t_eval):
@@ -1383,10 +1386,11 @@ class VectorizedIPLSSimulation:
         y_te = jnp.asarray(self.y_test)
 
         def build_W(V, C, idx):
-            tbl = jnp.concatenate([V, C.reshape(A * K, S)], axis=0)
-            return jnp.concatenate(
-                [tbl[idx[:, k], : sizes[k]] for k in range(K)], axis=1
-            )
+            with jax.named_scope("weight_build"):
+                tbl = jnp.concatenate([V, C.reshape(A * K, S)], axis=0)
+                return jnp.concatenate(
+                    [tbl[idx[:, k], : sizes[k]] for k in range(K)], axis=1
+                )
 
         def pre(V, C, Vstart_hist, Vagg_hist, c0_mask, c0_src):
             """Phase 0: roll the start-of-round value ring, apply the cache
@@ -1396,16 +1400,19 @@ class VectorizedIPLSSimulation:
             replica merges) saw the payload after one trip over the wire, so
             under int8 the authoritative V stays raw while the ring entry is
             its quantize->dequantize image."""
-            V0 = qdq_rows(V) if int8 else V
-            Vstart_new = jnp.concatenate([V0[None], Vstart_hist[:-1]], axis=0)
-            parts0 = [
-                Vstart_new.reshape(HD * K_inst, S),
-                Vagg_hist.reshape(HD * K_inst, S),
-            ]
-            if MAIL:
-                parts0.append(V_mail_j)
-            T0 = jnp.concatenate(parts0, axis=0)
-            C0 = jnp.where(c0_mask[:, :, None], T0[c0_src], C)
+            with jax.named_scope("wire_codec"):
+                V0 = qdq_rows(V) if int8 else V
+            with jax.named_scope("ring_roll"):
+                Vstart_new = jnp.concatenate([V0[None], Vstart_hist[:-1]], axis=0)
+            with jax.named_scope("weight_build"):
+                parts0 = [
+                    Vstart_new.reshape(HD * K_inst, S),
+                    Vagg_hist.reshape(HD * K_inst, S),
+                ]
+                if MAIL:
+                    parts0.append(V_mail_j)
+                T0 = jnp.concatenate(parts0, axis=0)
+                C0 = jnp.where(c0_mask[:, :, None], T0[c0_src], C)
             W = build_W(V, C0, widx_act)
             return Vstart_new, C0, W
 
@@ -1429,12 +1436,14 @@ class VectorizedIPLSSimulation:
             the CPU path the ring carries the dequantized (A, N) plane with
             raw owner slices mixed in."""
             if int8:
-                Dplane = D_now[:, col_ks] * valid_ksf[None]  # (A, K, S)
-                qn, scn, ne = quantize_rows(Dplane, E)
-                # offline agents never send, so their error-feedback
-                # residuals must freeze exactly like the scalar dict entries
-                keep3 = owner3 if full_active else (owner3 | ~act3)
-                E_new = jnp.where(keep3, E, ne)
+                with jax.named_scope("contrib_gather"):
+                    Dplane = D_now[:, col_ks] * valid_ksf[None]  # (A, K, S)
+                with jax.named_scope("wire_codec"):
+                    qn, scn, ne = quantize_rows(Dplane, E)
+                    # offline agents never send, so their error-feedback
+                    # residuals must freeze exactly like the scalar dict entries
+                    keep3 = owner3 if full_active else (owner3 | ~act3)
+                    E_new = jnp.where(keep3, E, ne)
             else:
                 E_new = E
             if int8 and use_kernel:
@@ -1442,24 +1451,27 @@ class VectorizedIPLSSimulation:
                 # (owner excluded from kidx by the control plane; its raw
                 # delta enters through the kernel's dedicated own-input)
                 Q_hist, S_hist = ring
-                Q_all = jnp.concatenate([qn[None], Q_hist], axis=0).reshape(LA, K, S)
-                S_all = jnp.concatenate([scn[None], S_hist], axis=0).reshape(LA, K, WNB)
-                G_q = Q_all[kidx, inst_k_j[:, None]]     # (K_inst, R, S)
-                G_s = S_all[kidx, inst_k_j[:, None]]     # (K_inst, R, WNB)
-                d_own = Dplane[inst_owner_j, inst_k_j]   # (K_inst, S)
+                with jax.named_scope("contrib_gather"):
+                    Q_all = jnp.concatenate([qn[None], Q_hist], axis=0).reshape(LA, K, S)
+                    S_all = jnp.concatenate([scn[None], S_hist], axis=0).reshape(LA, K, WNB)
+                    G_q = Q_all[kidx, inst_k_j[:, None]]     # (K_inst, R, S)
+                    G_s = S_all[kidx, inst_k_j[:, None]]     # (K_inst, R, WNB)
+                    d_own = Dplane[inst_owner_j, inst_k_j]   # (K_inst, S)
                 V_agg = aggregate_batched_q(
                     V, d_own, G_q, G_s, kmask,
                     jnp.ones((K_inst,), jnp.float32), eps_new,
                 )
-                ring_new = (
-                    jnp.concatenate([qn[None], Q_hist], axis=0)[:Lu],
-                    jnp.concatenate([scn[None], S_hist], axis=0)[:Lu],
-                )
+                with jax.named_scope("ring_roll"):
+                    ring_new = (
+                        jnp.concatenate([qn[None], Q_hist], axis=0)[:Lu],
+                        jnp.concatenate([scn[None], S_hist], axis=0)[:Lu],
+                    )
             else:
                 if int8:
                     # wire image of this round's delta plane: dequantized
                     # slices for remote readers, raw slices at owner positions
-                    deq = dequantize_rows(qn, scn)
+                    with jax.named_scope("wire_codec"):
+                        deq = dequantize_rows(qn, scn)
                     D_use = jnp.concatenate(
                         [
                             jnp.where(
@@ -1482,11 +1494,12 @@ class VectorizedIPLSSimulation:
                     # kernel, in scalar DELIVERY order (kidx), so the kernel's
                     # sequential masked-sum associates exactly like the
                     # scalar oracle's np.sum over pending deltas
-                    lane = jnp.arange(S, dtype=jnp.int32)
-                    valid = lane[None, :] < size_inst[:, None]
-                    col = jnp.where(valid, off_inst[:, None] + lane[None, :], 0)
-                    G = D_all[kidx[:, :, None], col[:, None, :]]
-                    G = G * valid[:, None, :]
+                    with jax.named_scope("contrib_gather"):
+                        lane = jnp.arange(S, dtype=jnp.int32)
+                        valid = lane[None, :] < size_inst[:, None]
+                        col = jnp.where(valid, off_inst[:, None] + lane[None, :], 0)
+                        G = D_all[kidx[:, :, None], col[:, None, :]]
+                        G = G * valid[:, None, :]
                     V_agg = aggregate_batched(V, G, kmask, eps_new)
                 else:
                     # CPU/GPU: per-partition static column slice + whole-row
@@ -1511,7 +1524,8 @@ class VectorizedIPLSSimulation:
                         parts.append(jnp.pad(agg_k, ((0, 0), (0, S - sz))))
                     agg = jnp.concatenate(parts, axis=0)
                     V_agg = V - eps_new[:, None] * agg
-                ring_new = jnp.concatenate([D_use[None], ring], axis=0)[:Lu]
+                with jax.named_scope("ring_roll"):
+                    ring_new = jnp.concatenate([D_use[None], ring], axis=0)[:Lu]
             # pin ONE materialization before the merge gathers the wire
             # image: a recompute at the gather site may pick a different FMA
             # contraction than the direct use (see the PERFECT-path barrier)
@@ -1519,40 +1533,40 @@ class VectorizedIPLSSimulation:
             # everything a post-aggregate value feeds (UpdateModel-reply
             # cache writes, replica publishes) crossed the wire: ring/table
             # the wire image, keep the authoritative V_agg raw
-            V_aggw = qdq_rows(V_agg) if int8 else V_agg
+            with jax.named_scope("wire_codec"):
+                V_aggw = qdq_rows(V_agg) if int8 else V_agg
             # replica consensus: mean of self + version-kept arrived values
             # (late values read the post-aggregate ring at their send age).
             # Sequential adds in the control plane's landing-tick order keep
             # the association identical to the scalar np.mean over
             # [self] + arrivals.
-            Vm_flat = jnp.concatenate(
-                [V_aggw[None], Vagg_hist[: HD - 1]], axis=0
-            ).reshape(HD * K_inst, S)
-            if MAIL:
-                Vm_flat = jnp.concatenate([Vm_flat, V_mail_j], axis=0)
-            acc = V_agg
-            for j in range(MW):
-                acc = jnp.where(mmask[:, j, None] > 0, acc + Vm_flat[msrc[:, j]], acc)
-            V_new = acc / (1.0 + merge_cnt)[:, None]
-            # phase-2 cache updates (may reference this round's post-agg table)
-            parts2 = [
-                Vstart_new.reshape(HD * K_inst, S),
-                Vagg_hist.reshape(HD * K_inst, S),
-                V_aggw,
-            ]
-            if MAIL:
-                parts2.append(V_mail_j)
-            T2 = jnp.concatenate(parts2, axis=0)
-            C2 = jnp.where(c2_mask[:, :, None], T2[c2_src], C0)
-            Vagg_hist_new = jnp.concatenate([V_aggw[None], Vagg_hist[:-1]], axis=0)
+            with jax.named_scope("replica_merge"):
+                Vm_flat = jnp.concatenate(
+                    [V_aggw[None], Vagg_hist[: HD - 1]], axis=0
+                ).reshape(HD * K_inst, S)
+                if MAIL:
+                    Vm_flat = jnp.concatenate([Vm_flat, V_mail_j], axis=0)
+                acc = V_agg
+                for j in range(MW):
+                    acc = jnp.where(mmask[:, j, None] > 0, acc + Vm_flat[msrc[:, j]], acc)
+                V_new = acc / (1.0 + merge_cnt)[:, None]
+                # phase-2 cache updates (may reference this round's post-agg table)
+                parts2 = [
+                    Vstart_new.reshape(HD * K_inst, S),
+                    Vagg_hist.reshape(HD * K_inst, S),
+                    V_aggw,
+                ]
+                if MAIL:
+                    parts2.append(V_mail_j)
+                T2 = jnp.concatenate(parts2, axis=0)
+                C2 = jnp.where(c2_mask[:, :, None], T2[c2_src], C0)
+            with jax.named_scope("ring_roll"):
+                Vagg_hist_new = jnp.concatenate([V_aggw[None], Vagg_hist[:-1]], axis=0)
             return V_new, C2, ring_new, Vagg_hist_new, E_new
 
         def eval_lossy(V_new, C2):
             # evaluate the sub-sampled agents on end-of-round state
-            tbl_eval = jnp.concatenate([V_new, C2.reshape(A * K, S)], axis=0)
-            W_eval = jnp.concatenate(
-                [tbl_eval[widx_eval[:, k], : sizes[k]] for k in range(K)], axis=1
-            )
+            W_eval = build_W(V_new, C2, widx_eval)
             return jax.vmap(
                 lambda w: mlp_mnist.evaluate(unflatten_params(w, layout), x_te, y_te)
             )(W_eval)
@@ -2072,21 +2086,15 @@ class VectorizedIPLSSimulation:
                 xs, ys = self._draw_batches()
                 Xw.append(xs)
                 Yw.append(ys)
-        Xs = tuple(
-            jnp.asarray(np.stack([np.stack(Xw[w][lo:hi]) for w in range(W)]))
-            for lo, hi, _ in self._buckets
-        )
-        Ys = tuple(
-            jnp.asarray(np.stack([np.stack(Yw[w][lo:hi]) for w in range(W)]))
-            for lo, hi, _ in self._buckets
-        )
-        stack = lambda key: jnp.asarray(np.stack([c[key] for c in ctls]))
-        des = jnp.asarray([self._do_eval(r0 + w) for w in range(W)])
-        xs_all = (
-            Xs, Ys, stack("c0_mask"), stack("c0_src"), stack("msrc"),
-            stack("eps"), stack("mmask"), stack("cnt"), stack("c2_mask"),
-            stack("c2_src"), stack("kidx"), stack("kmask"), des,
-        )
+        with pt.phase("stage"):
+            Xs, Ys = self._stage_batches(Xw, Yw)
+            stack = lambda key: jnp.asarray(np.stack([c[key] for c in ctls]))
+            des = jnp.asarray([self._do_eval(r0 + w) for w in range(W)])
+            xs_all = (
+                Xs, Ys, stack("c0_mask"), stack("c0_src"), stack("msrc"),
+                stack("eps"), stack("mmask"), stack("cnt"), stack("c2_mask"),
+                stack("c2_src"), stack("kidx"), stack("kmask"), des,
+            )
         with pt.phase("device_window"):
             carry, ys = self._scan_window_j(
                 self._Vl, self._C, self._ring, self._Vagg_hist,
@@ -2099,23 +2107,35 @@ class VectorizedIPLSSimulation:
             self._Vstart_hist, self._E,
         ) = carry
         self.device_dispatches += 1
-        mets = None
-        if self.recorder is not None:
-            accs, mets = ys
-            mets = np.asarray(mets, np.float32)
-        else:
-            accs = ys
-        accs = np.asarray(accs, np.float32)
-        for w in range(W):
-            c = ctls[w]
-            self.messages_sent += c["msgs"]
-            self.messages_dropped += c["drops"]
-            self._bytes_total += c["nbytes"]
-            self.history.append(self._metrics_entry(r0 + w, accs[w]))
+        with pt.phase("wait"):
+            mets = None
             if self.recorder is not None:
-                self._emit_row(r0 + w, c["r_vec"], c["eps64"], mets[w])
+                accs, mets = ys
+                mets = np.asarray(mets, np.float32)
+            else:
+                accs = ys
+            accs = np.asarray(accs, np.float32)
+        with pt.phase("account"):
+            for w in range(W):
+                c = ctls[w]
+                self.messages_sent += c["msgs"]
+                self.messages_dropped += c["drops"]
+                self._bytes_total += c["nbytes"]
+                self.history.append(self._metrics_entry(r0 + w, accs[w]))
+                if self.recorder is not None:
+                    self._emit_row(r0 + w, c["r_vec"], c["eps64"], mets[w])
 
     # -- one round ----------------------------------------------------------
+    def _stage_batches(self, Xw, Yw):
+        """A window's drawn batches as per-bucket (W, rows, ...) device
+        arrays, the scan xs' batch part."""
+        W = len(Xw)
+        stack = lambda B: tuple(
+            jnp.asarray(np.stack([np.stack(B[w][lo:hi]) for w in range(W)]))
+            for lo, hi, _ in self._buckets
+        )
+        return stack(Xw), stack(Yw)
+
     def _draw_batches(self):
         # only the ONLINE agents' RNG streams advance — the scalar train
         # phase skips offline agents, so their trainers must not draw
@@ -2295,32 +2315,26 @@ class VectorizedIPLSSimulation:
                 xs, ys = self._draw_batches()
                 Xw.append(xs)
                 Yw.append(ys)
-        Xs = tuple(
-            jnp.asarray(np.stack([np.stack(Xw[w][lo:hi]) for w in range(W)]))
-            for lo, hi, _ in self._buckets
-        )
-        Ys = tuple(
-            jnp.asarray(np.stack([np.stack(Yw[w][lo:hi]) for w in range(W)]))
-            for lo, hi, _ in self._buckets
-        )
-        prev = self._last_phase
-        t_prev_l, idx_l, mask_l, M_l, t_eval_l, de_l = [], [], [], [], [], []
-        for w in range(W):
-            rnd = r0 + w
-            p = rnd % self._period
-            t_prev_l.append(self._t_inst[prev])
-            idx_l.append(self._contrib_idx[p])
-            mask_l.append(self._contrib_mask[p])
-            M_l.append(self._contrib_M[p])
-            t_eval_l.append(self._t_inst[p][self._eval_idx])
-            de_l.append(self._do_eval(rnd))
-            prev = p
-        xs_all = (
-            Xs, Ys,
-            jnp.asarray(np.stack(t_prev_l)), jnp.asarray(np.stack(idx_l)),
-            jnp.asarray(np.stack(mask_l)), jnp.asarray(np.stack(M_l)),
-            jnp.asarray(np.stack(t_eval_l)), jnp.asarray(np.asarray(de_l, bool)),
-        )
+        with pt.phase("stage"):
+            Xs, Ys = self._stage_batches(Xw, Yw)
+            prev = self._last_phase
+            t_prev_l, idx_l, mask_l, M_l, t_eval_l, de_l = [], [], [], [], [], []
+            for w in range(W):
+                rnd = r0 + w
+                p = rnd % self._period
+                t_prev_l.append(self._t_inst[prev])
+                idx_l.append(self._contrib_idx[p])
+                mask_l.append(self._contrib_mask[p])
+                M_l.append(self._contrib_M[p])
+                t_eval_l.append(self._t_inst[p][self._eval_idx])
+                de_l.append(self._do_eval(rnd))
+                prev = p
+            xs_all = (
+                Xs, Ys,
+                jnp.asarray(np.stack(t_prev_l)), jnp.asarray(np.stack(idx_l)),
+                jnp.asarray(np.stack(mask_l)), jnp.asarray(np.stack(M_l)),
+                jnp.asarray(np.stack(t_eval_l)), jnp.asarray(np.asarray(de_l, bool)),
+            )
         with pt.phase("device_window"):
             out = self._scan_window_j(
                 self._V_pre, self._V_merged, self._eps, xs_all
@@ -2330,17 +2344,20 @@ class VectorizedIPLSSimulation:
         mets = None
         if self.recorder is not None:
             self._V_pre, self._V_merged, self._eps, accs, mets = out
-            mets = np.asarray(mets, np.float32)
         else:
             self._V_pre, self._V_merged, self._eps, accs = out
         self.device_dispatches += 1
         self._last_phase = prev
-        accs = np.asarray(accs, np.float32)
-        for w in range(W):
-            self._perfect_traffic(r0 + w)
-            self.history.append(self._metrics_entry(r0 + w, accs[w]))
-            if self.recorder is not None:
-                self._emit_perfect(r0 + w, mets[w])
+        with pt.phase("wait"):
+            if mets is not None:
+                mets = np.asarray(mets, np.float32)
+            accs = np.asarray(accs, np.float32)
+        with pt.phase("account"):
+            for w in range(W):
+                self._perfect_traffic(r0 + w)
+                self.history.append(self._metrics_entry(r0 + w, accs[w]))
+                if self.recorder is not None:
+                    self._emit_perfect(r0 + w, mets[w])
 
     def run_window(self, start_rnd: int, window: int) -> List[dict]:
         """Run `window` consecutive rounds as ONE lax.scan-driven device
@@ -2350,7 +2367,10 @@ class VectorizedIPLSSimulation:
         if window < 1:
             raise ValueError("window must be >= 1")
         n0 = len(self.history)
-        if self._lossy:
+        with self._pt.phase("run_window"):
+            if not self._lossy:
+                self._run_window_perfect(start_rnd, window)
+                return self.history[n0:]
             # a window may not span a membership event: fall back to
             # round-at-a-time (which replays event rounds on the oracle)
             ok = not any(
@@ -2363,8 +2383,6 @@ class VectorizedIPLSSimulation:
             else:
                 for w in range(window):
                     self.run_round(start_rnd + w)
-        else:
-            self._run_window_perfect(start_rnd, window)
         return self.history[n0:]
 
     def run(self) -> List[dict]:

@@ -42,7 +42,7 @@ class MetricsRecorder:
     ):
         self.rows: List[dict] = []
         self.trace = trace
-        self.timer = PhaseTimer(trace=trace)
+        self.timer = PhaseTimer()
         self._ticks = int(ticks_per_round)
         self._bins = int(max_delay_ticks) + 1
         self._acc: Dict[int, dict] = {}  # round -> in-progress traffic row

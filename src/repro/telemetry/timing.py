@@ -1,17 +1,20 @@
-"""Per-phase wall timers: dispatch-level attribution for the round engines.
+"""Per-phase host spans and wall timers for the round engines.
 
-``PhaseTimer.phase(name)`` is a context manager accumulating count/seconds
-per phase; when a ``TraceWriter`` is attached every phase also lands as a
-Chrome trace "X" (complete) event on the host-wall-clock track. The
-``NULL_TIMER`` singleton is what engines hold when telemetry is disabled —
-its ``phase()`` is a shared no-op context manager, so the disabled-path
-cost is one attribute lookup per phase.
+``PhaseTimer.phase(name)`` and ``NULL_TIMER.phase(name)`` both open a
+``jax.profiler.TraceAnnotation`` named ``host.<name>`` around the phase, so
+every engine phase lands in the profiler's trace on the clock its device
+planes share, whether telemetry is on or off. When no trace is being taken
+a span costs one TraceMe check. ``PhaseTimer`` (held with
+``telemetry=True``) also accumulates count/seconds per phase; ``NULL_TIMER``
+(held otherwise) keeps no totals.
 """
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class PhaseTimer:
@@ -24,22 +27,19 @@ class PhaseTimer:
 
     sync = True
 
-    def __init__(self, trace=None):
-        self.trace = trace
+    def __init__(self):
         self.totals: Dict[str, list] = {}  # name -> [count, seconds]
 
     @contextmanager
     def phase(self, name: str):
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(f"host.{name}"):
+                yield
         finally:
-            dt = time.perf_counter() - t0
             ent = self.totals.setdefault(name, [0, 0.0])
             ent[0] += 1
-            ent[1] += dt
-            if self.trace is not None:
-                self.trace.host_span(name, t0, dt)
+            ent[1] += time.perf_counter() - t0
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {
@@ -52,9 +52,8 @@ class _NullTimer:
     sync = False
     totals: Dict[str, list] = {}
 
-    @contextmanager
     def phase(self, name: str):
-        yield
+        return TraceAnnotation(f"host.{name}")
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {}

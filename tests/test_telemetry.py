@@ -8,15 +8,18 @@ disabled, the engines compute bitwise-identical results to the enabled run
 (the metric aux outputs observe, never perturb).
 """
 import dataclasses
+import glob
 import json
+import os
 
+import jax
 import numpy as np
 import pytest
 
 from repro.data import iid_split, synth_mnist
 from repro.fl import SimConfig, make_simulation
 from repro.p2p.network import LOSSY, PERFECT, NetworkConditions
-from repro.telemetry import MetricsRecorder, PhaseTimer, TraceWriter
+from repro.telemetry import NULL_TIMER, MetricsRecorder, PhaseTimer
 from repro.telemetry.report import load_stream, main as report_main, summarize
 from repro.telemetry.schema import CHANNELS, ROW_KEYS, SCHEMA_VERSION
 
@@ -146,32 +149,105 @@ def test_trace_events_are_chrome_trace_shaped(data, tmp_path):
     doc = trace.to_dict()
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
     events = doc["traceEvents"]
-    phases = {e["ph"] for e in events}
-    assert {"M", "i", "X"} <= phases  # metadata + protocol instants + spans
+    # metadata + protocol instants; host phases live in the profiler's trace
+    assert {e["ph"] for e in events} == {"M", "i"}
     for ev in events:
         assert {"name", "ph", "pid"} <= set(ev)
-        if ev["ph"] in ("i", "X"):
+        assert ev["pid"] == 1
+        if ev["ph"] == "i":
             assert ev["ts"] >= 0
-    # both tracks populated: simulated-tick protocol + wall-clock host
-    assert any(e["pid"] == 1 and e["ph"] == "i" for e in events)
-    assert any(e["pid"] == 2 and e["ph"] == "X" for e in events)
     # round-trips through json on disk
     out = tmp_path / "run.trace.json"
     trace.write(str(out))
     assert json.loads(out.read_text())["traceEvents"]
 
 
-def test_phase_timer_accumulates_and_traces():
-    tw = TraceWriter()
-    pt = PhaseTimer(trace=tw)
-    with pt.phase("fate_draw"):
-        pass
-    with pt.phase("fate_draw"):
-        pass
+def _host_spans(log_dir) -> list:
+    """[(start_ns, end_ns, name)] of the ``host.`` spans in a profiler
+    trace, in start order."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("host."):
+                    out.append((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name))
+    return sorted(out)
+
+
+def test_phase_timer_accumulates_and_traces(tmp_path):
+    pt = PhaseTimer()
+    with jax.profiler.trace(str(tmp_path)):
+        with pt.phase("fate_draw"):
+            pass
+        with pt.phase("fate_draw"):
+            pass
+        with NULL_TIMER.phase("control"):
+            pass
     s = pt.summary()
     assert s["fate_draw"]["count"] == 2
     assert s["fate_draw"]["total_s"] >= 0
-    assert len(tw.events) == 2
+    assert "control" not in s and NULL_TIMER.summary() == {}
+    names = [n for _, _, n in _host_spans(tmp_path)]
+    assert names == ["host.fate_draw", "host.fate_draw", "host.control"]
+
+
+# every host step of one scanned window, in the order the engine takes them
+WINDOW_PHASES = {
+    "perfect": ("batches", "stage", "device_window", "wait", "account"),
+    "lossy": (
+        "fate_draw", "control", "batches", "stage", "device_window", "wait", "account",
+    ),
+}
+
+
+def _window_sim(data, conditions, wire_dtype="f32", seed=0):
+    x_tr, y_tr, x_te, y_te = data
+    cfg = SimConfig(
+        num_agents=6, num_partitions=5, pi=2, rho=2, rounds=100, local_iters=2,
+        batch_size=32, eval_agents=2, engine="vectorized", scan_rounds=2,
+        conditions=conditions, wire_dtype=wire_dtype, seed=seed,
+    )
+    shards = iid_split(x_tr, y_tr, cfg.num_agents, seed=0)
+    return make_simulation(cfg, shards, x_te, y_te)
+
+
+@pytest.mark.parametrize(
+    "path,conditions,wire",
+    [("perfect", PERFECT, "f32"), ("lossy", LOSSY, "int8")],
+    ids=["perfect", "lossy-int8"],
+)
+def test_window_phases_are_profiler_spans(data, tmp_path, path, conditions, wire):
+    sim = _window_sim(data, conditions, wire)
+    assert sim.recorder is None  # telemetry off: the spans need no timer
+    sim.run_window(0, 2)  # compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        sim.run_window(2, 2)
+    spans = _host_spans(tmp_path)
+    outer = [s for s in spans if s[2] == "host.run_window"]
+    assert len(outer) == 1
+    t0, t1, _ = outer[0]
+    inner = [s for s in spans if s[2] != "host.run_window"]
+    assert [n for _, _, n in inner] == [f"host.{p}" for p in WINDOW_PHASES[path]]
+    for a, b, _ in inner:
+        assert t0 <= a <= b <= t1
+    # the phases follow one another and do not overlap
+    for (_, b, _), (a, _, _) in zip(inner, inner[1:]):
+        assert b <= a
+
+
+@pytest.mark.parametrize(
+    "conditions,wire", [(PERFECT, "f32"), (LOSSY, "int8")], ids=["perfect", "lossy-int8"]
+)
+def test_profiler_changes_nothing(data, tmp_path, conditions, wire):
+    on, off = (_window_sim(data, conditions, wire, seed=5) for _ in range(2))
+    with jax.profiler.trace(str(tmp_path)):
+        hist_on = on.run_window(0, 2) + on.run_window(2, 2)
+    hist_off = off.run_window(0, 2) + off.run_window(2, 2)
+    assert hist_on == hist_off
+    np.testing.assert_array_equal(on.agent_weights(), off.agent_weights())
 
 
 # ---- report CLI -------------------------------------------------------------

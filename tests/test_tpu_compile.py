@@ -19,6 +19,7 @@ one process may load the TPU library, and under pytest-xdist each worker
 imports every test file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +119,68 @@ def test_dequantize_compiles(one_chip, N):
         _sds((num_blocks(N),), jnp.float32, one_chip),
     )
     assert "tpu_custom_call" in text
+
+
+class _Captured(Exception):
+    pass
+
+
+def _window_program(conditions, wire_dtype, sharding):
+    """The engine's scanned window program at a tiny fleet on the kernel
+    path, and its argument shapes placed on ``sharding``: the engine's first
+    call to it is stopped once its arguments are seen, so nothing runs."""
+    from repro.data import iid_split, synth_mnist
+    from repro.fl import SimConfig
+    from repro.fl.vectorized import VectorizedIPLSSimulation
+
+    x_tr, y_tr, x_te, y_te = synth_mnist(num_train=400, num_test=64, seed=0)
+    cfg = SimConfig(
+        num_agents=4, num_partitions=4, pi=2, rho=2, rounds=100, local_iters=1,
+        batch_size=32, eval_agents=2, engine="vectorized", scan_rounds=2,
+        conditions=conditions, wire_dtype=wire_dtype,
+    )
+    sim = VectorizedIPLSSimulation(
+        cfg, iid_split(x_tr, y_tr, cfg.num_agents, seed=0), x_te, y_te, use_kernel=True
+    )
+    fn, seen = sim._scan_window_j, []
+
+    def spy(*args):
+        seen.append(jax.tree.map(lambda x: _sds(x.shape, x.dtype, sharding), args))
+        raise _Captured
+
+    sim._scan_window_j = spy
+    with pytest.raises(_Captured):
+        sim.run_window(0, 2)
+    return fn, seen[0]
+
+
+@pytest.mark.parametrize(
+    "path", ["perfect-f32", "lossy-int8"], ids=["perfect-f32", "lossy-int8"]
+)
+def test_window_program_carries_scopes(one_chip, monkeypatch, path):
+    """Each device sub-layer of `gather and merge` is a named scope, and the
+    scopes survive the TPU compiler into the op_name metadata the benchmark's
+    trace reader maps device ops through."""
+    from repro.kernels.ipls_aggregate import ipls_aggregate
+    from repro.p2p.network import LOSSY, PERFECT
+
+    if path == "perfect-f32":
+        fn, args = _window_program(PERFECT, "f32", one_chip)
+        scopes = ("weight_build", "contrib_gather", "replica_merge")
+    else:
+        fn, args = _window_program(LOSSY, "int8", one_chip)
+        scopes = ("weight_build", "contrib_gather", "wire_codec", "ring_roll", "replica_merge")
+    # the kernel picks its mode from the default backend (the CPU here)
+    monkeypatch.setattr(ipls_aggregate, "default_interpret", lambda: False)
+    try:
+        text = _compiled_text(fn, *args)
+    finally:
+        # the kernel's jit caches its trace under interpret=None: the trace
+        # made for the TPU must not serve a later CPU call of the same shapes
+        jax.clear_caches()
+    assert "tpu_custom_call" in text
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
+    # the kernel keeps the path the benchmark's readers find it by
+    assert any(n.endswith("pallas_call") and "jit(ipls_aggregate_batched" in n for n in names)
