@@ -14,7 +14,7 @@ import pytest
 
 from repro.data import iid_split, synth_mnist
 from repro.fl import IPLSSimulation, SimConfig, make_simulation
-from repro.p2p.network import LOSSY, NetworkConditions
+from repro.p2p.network import LOSSY, PERFECT, NetworkConditions
 
 RNG = np.random.default_rng(3)
 
@@ -119,6 +119,80 @@ def test_lossy_kernel_path_matches_scalar(data):
     w_s = np.stack([sim_s.agents[a].load_model() for a in range(cfg.num_agents)])
     np.testing.assert_allclose(w_s, sim_v.agent_weights(), atol=1e-4)
     assert sim_s.net.pubsub.total_bytes() == sim_v._bytes_total
+
+
+@pytest.mark.parametrize(
+    "conditions, wire_dtype",
+    [(PERFECT, "f32"), (LOSSY, "f32"), (LOSSY, "int8")],
+    ids=["perfect-f32", "lossy-f32", "lossy-int8"],
+)
+def test_kernel_path_matches_scalar_uneven_partitions(data, conditions, wire_dtype):
+    """Partitions of unequal size (K does not divide N): the kernel path's
+    contributor layout zero-pads the narrower partitions to S, interpret
+    mode off-TPU."""
+    from repro.fl.vectorized import VectorizedIPLSSimulation
+
+    x_tr, y_tr, x_te, y_te = data
+    cfg = SimConfig(
+        num_agents=4, num_partitions=8, pi=2, rho=2, rounds=3, local_iters=2,
+        conditions=conditions, wire_dtype=wire_dtype, scan_rounds=3, seed=0,
+    )
+    shards = iid_split(x_tr, y_tr, cfg.num_agents, seed=0)
+    sim_s = IPLSSimulation(cfg, shards, x_te, y_te)
+    sim_s.run()
+    sim_v = VectorizedIPLSSimulation(cfg, shards, x_te, y_te, use_kernel=True)
+    assert len(set(sim_v._sizes.tolist())) > 1
+    sim_v.run()
+    w_s = np.stack([sim_s.agents[a].load_model() for a in range(cfg.num_agents)])
+    np.testing.assert_allclose(w_s, sim_v.agent_weights(), atol=1e-4)
+    assert sim_s.net.pubsub.total_bytes() == sim_v._bytes_total
+    assert sim_s.net.pubsub.messages_sent == sim_v.messages_sent
+    assert sim_s.net.pubsub.messages_dropped == sim_v.messages_dropped
+
+
+@pytest.mark.parametrize(
+    "N, K, S_extra",
+    [(1000, 5, 0), (1003, 4, 0), (1000, 5, 24), (1003, 4, 5)],
+    ids=["even", "uneven", "even-widened", "uneven-widened"],
+)
+def test_partition_slices_match_element_gather(N, K, S_extra):
+    """`contributor_rows` (the kernel path's contributor layout) and
+    `partition_plane` (the int8 delta plane) give what the element-indexed
+    (row, column) gathers gave: every in-partition lane bitwise, every pad
+    lane +0.0 (the old gathers' mask multiply left -0.0 wherever column 0
+    was negative). `S_extra` widens S past the largest partition, as the
+    int8 wire rounds it up to whole quantization blocks."""
+    from repro.core.partition import PartitionSpec
+    from repro.fl.vectorized import contributor_rows, partition_plane
+
+    spec = PartitionSpec.even(N, K)
+    sizes, offsets = np.asarray(spec.sizes), np.asarray(spec.offsets())
+    S = int(sizes.max()) + S_extra
+    rows, R = 7, 5
+    inst_k = np.repeat(np.arange(K), 2).astype(np.int32)   # rho = 2, k-major
+    inst_of_k = [np.nonzero(inst_k == k)[0] for k in range(K)]
+    idx = RNG.integers(0, rows, (len(inst_k), R)).astype(np.int32)
+    D = jnp.asarray(RNG.standard_normal((rows, N)), jnp.float32)
+
+    lane = np.arange(S)
+    valid = lane[None, :] < sizes[:, None]                    # (K, S)
+    col = np.where(valid, offsets[:, None] + lane[None, :], 0)
+
+    def bits(x, keep):
+        return np.where(keep, np.asarray(x).view(np.uint32), np.uint32(0))
+
+    # contributor layout (K_inst, R, S), the kernel path's G
+    G_old = D[idx[:, :, None], col[inst_k][:, None, :]] * valid[inst_k][:, None, :]
+    G_new = contributor_rows(
+        D, jnp.asarray(idx), inst_of_k=inst_of_k, offsets=offsets, sizes=sizes, S=S
+    )
+    keep = np.broadcast_to(valid[inst_k][:, None, :], G_old.shape)
+    np.testing.assert_array_equal(np.asarray(G_new).view(np.uint32), bits(G_old, keep))
+    # int8 delta plane (rows, K, S)
+    Dplane_old = D[:, col] * jnp.asarray(valid, jnp.float32)[None]
+    P = partition_plane(D, offsets=offsets, sizes=sizes, S=S)
+    keep = np.broadcast_to(valid[None], Dplane_old.shape)
+    np.testing.assert_array_equal(np.asarray(P).view(np.uint32), bits(Dplane_old, keep))
 
 
 def test_vectorized_rejects_out_of_scope_configs(data):
