@@ -13,6 +13,7 @@ from __future__ import annotations
 import gc
 import time
 
+from benchmarks.chip import flops
 from benchmarks.chip import traffic as gen
 from benchmarks.chip.common import Compiles, span
 from benchmarks.chip.reference import compare, ipls
@@ -146,3 +147,9 @@ class FleetRun:
 
 def build(config: dict, traffic: dict, seed: int, annotate: bool = False) -> FleetRun:
     return FleetRun(config, traffic, seed, annotate)
+
+
+def round_flops(config: dict, traffic: dict) -> float:
+    """Model FLOPs of one round: every agent's local SGD and the round's
+    share of the evaluations (flops.py)."""
+    return flops.fleet_round_flops(config, traffic)

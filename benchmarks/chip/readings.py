@@ -1,11 +1,14 @@
-"""The readings that the limits of a fleet cell are set from, at the cell's
-own size, on the chip, in one process:
+"""The readings that the limits of a cell are set from, at the cell's own
+size, on the chip, in one process:
 
     python3 benchmarks/chip/readings.py --workload <cell> \
         --program 1,2,...   --control 1,2,3   --faults 1,2,3
 
 For each seed it runs the plain float32 reference over the first window's
-rounds and compares with it, by the cell's numbers (reference/compare.py):
+rounds and compares with it, by the cell's numbers. One JSON line per seed
+and variant on standard output. The benchmark's own runs do not run this.
+
+A fleet cell (entry ``fused_engine``; reference/compare.py):
 
   program         the program as the benchmark runs it (the lower reading)
   control         the reference with local SGD in bfloat16, in the program's
@@ -13,8 +16,13 @@ rounds and compares with it, by the cell's numbers (reference/compare.py):
   half_batch      the program with local SGD on half of each batch
   state_unchanged the program with local SGD that returns its input
 
-One JSON line per seed and variant on standard output. The benchmark's own
-runs do not run this.
+A mesh cell (entry ``mesh_step``; reference/mesh_step.py): ``program`` as
+above; the controls and faults are the reference's ``VARIANTS`` put in the
+program's place (``--control`` runs ``control_fp8``, ``--faults`` the rest):
+the matrix products' inputs in float8 (the control); the learning
+rate halved, eps off, absent agents counted in, half of the batch left out,
+one layer's gradient dropped, the exchange left out (each partition updated
+from its owner's rows alone).
 """
 import argparse
 import json
@@ -58,21 +66,31 @@ def main(argv=None) -> int:
     ap.add_argument("--faults", default="")
     args = ap.parse_args(argv)
 
-    import jax.numpy as jnp
-
     from benchmarks.chip import common
     from benchmarks.chip import run as harness
+
+    spec = harness.load_cell(args.workload)
+    common.use_compile_cache()
+    harness.devices_for(spec["cell"]["chips"], require_chip=True)
+    read = {"fused_engine": fleet_readings, "mesh_step": mesh_readings}[spec["config"]["entry"]]
+    for seed, what, numbers, seconds in read(spec, args):
+        print(json.dumps({
+            "workload": args.workload, "seed": seed, "variant": what, **numbers,
+            "seconds": seconds,
+        }), flush=True)
+    return 0
+
+
+def fleet_readings(spec: dict, args):
+    import jax.numpy as jnp
+
     from benchmarks.chip import traffic as gen
     from benchmarks.chip.entries import fused_engine
     from benchmarks.chip.reference import compare, ipls
     from repro.models import mlp_mnist
 
-    spec = harness.load_cell(args.workload)
-    common.use_compile_cache()
-    harness.devices_for(spec["cell"]["chips"], require_chip=True)
     config, traffic = spec["config"], spec["traffic"]
     rounds = traffic["scan_rounds"]
-
     plan = [(s, "program") for s in _seeds(args.program)]
     plan += [(s, "control") for s in _seeds(args.control)]
     for kind in ("half_batch", "state_unchanged"):
@@ -101,12 +119,45 @@ def main(argv=None) -> int:
                 mlp_mnist.sgd_steps_flat = real
             got = run.first
             run.free()
-        numbers = compare.fleet_numbers(got, ref, x_te, y_te)
-        print(json.dumps({
-            "workload": args.workload, "seed": seed, "variant": what, **numbers,
-            "seconds": time.perf_counter() - t0,
-        }), flush=True)
-    return 0
+        yield seed, what, compare.fleet_numbers(got, ref, x_te, y_te), time.perf_counter() - t0
+
+
+def mesh_readings(spec: dict, args):
+    import jax
+
+    from benchmarks.chip import traffic as gen
+    from benchmarks.chip.entries import mesh_step
+    from benchmarks.chip.reference import mesh_step as ref
+
+    config, traffic = spec["config"], spec["traffic"]
+    n = config["mesh"]["data"]
+    devices = jax.devices()[:n]
+    program, control, faults = (set(_seeds(a)) for a in (args.program, args.control, args.faults))
+    for seed in sorted(program | control | faults):
+        t0 = time.perf_counter()
+        if seed in program:
+            run = mesh_step.build(config, traffic, seed)
+            got, batches = run.first, run.batches
+            run.free()
+            del run
+        else:
+            got, batches = None, [
+                gen.round_batch(traffic, n, config["model"]["vocab_size"], seed, r)
+                for r in range(traffic["first_window_rounds"])
+            ]
+        base = ref.run(config, traffic, seed, batches, devices)
+        if got is not None:
+            numbers = {**ref.numbers(got, base), "worst": ref.worst_leaves(got, base)}
+            yield seed, "program", numbers, time.perf_counter() - t0
+        for name, variant in ref.VARIANTS.items():
+            is_control = name.startswith("control")
+            if name == "program":
+                continue
+            if seed not in (control if is_control else faults):
+                continue
+            t0 = time.perf_counter()
+            low = ref.run(config, traffic, seed, batches, devices, variant)
+            yield seed, name, ref.numbers(low, base), time.perf_counter() - t0
 
 
 if __name__ == "__main__":

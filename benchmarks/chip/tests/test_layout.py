@@ -39,7 +39,7 @@ def test_cell_resolves(cell):
     limits = gen.load("limits", cell["name"])
     assert limits and all(v >= 0 for v in limits.values())
     entry = importlib.import_module(f"benchmarks.chip.entries.{config['entry']}")
-    assert callable(entry.build)
+    assert callable(entry.build) and callable(entry.round_flops)
     reported = [
         m for m in BENCH["end_to_end"] + BENCH["per_layer"]
         if cell["name"] in m.get("workloads", [cell["name"]])

@@ -90,3 +90,24 @@ def test_recorded_trace_partitions_busy_time(recorded):
     s = trace.summary(recorded)
     assert 0 < s["busy_s"] < s["window_s"]
     assert s["breakdown"]["device_ops"] and s["breakdown"]["idle_gaps"]
+
+
+def test_exchange_in_flight_and_exposed():
+    from benchmarks.chip.metrics import exchange_exposed_ms_per_round, exchange_ms_per_round
+
+    dev0 = [
+        [0 * MS, 2 * MS, "fusion.1", ""],
+        [1 * MS, 0.5 * MS, "all-gather-start.2", ""],  # in flight 1..4 ms
+        [3 * MS, 1 * MS, "all-gather-done.2", ""],
+        [5 * MS, 1 * MS, "all-reduce.3", ""],  # 5..6 ms, half under fusion.4
+        [5.5 * MS, 1 * MS, "fusion.4", ""],
+    ]
+    dev1 = [[0, 1 * MS, "all-reduce.3", ""]]
+    tr = {"devices": {"/device:TPU:0": dev0, "/device:TPU:1": dev1},
+          "host": [[0, 10 * MS, "bench.measure"]]}
+    ctx = types.SimpleNamespace(trace=tr, counts={"rounds": 1})
+    # chip 0: 3 + 1 ms in flight, 2 + 0.5 exposed; chip 1: 1 and 1
+    assert exchange_ms_per_round.read(ctx) == pytest.approx(2.5)
+    assert exchange_exposed_ms_per_round.read(ctx) == pytest.approx(1.75)
+    fleet = {"devices": {"/device:TPU:0": dev0[:1]}, "host": tr["host"]}
+    assert exchange_ms_per_round.read(types.SimpleNamespace(trace=fleet, counts={"rounds": 1})) is None

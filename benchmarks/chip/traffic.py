@@ -82,3 +82,39 @@ def fleet_inputs(config: dict, seed: int):
         d["num_train"], d["num_test"], d["num_classes"], d["noise"], d["data_seed"]
     )
     return iid_shards(x_tr, y_tr, config["fleet"]["num_agents"], seed), x_te, y_te
+
+
+# ------------------------------------------------------ token training ---
+def markov_tokens(num_sequences: int, seq_len: int, vocab: int, noise: float,
+                  rng: np.random.Generator) -> np.ndarray:
+    """Token sequences in which each next token is a fixed successor of the
+    one before (a seeded table over the vocabulary), or, with probability
+    ``noise``, a uniform draw: a language with signal to learn. (The same
+    process as ``repro.data.synthetic.synth_tokens``.)"""
+    successor = rng.integers(0, vocab, size=vocab)
+    toks = np.empty((num_sequences, seq_len), np.int32)
+    cur = rng.integers(0, vocab, size=num_sequences)
+    for t in range(seq_len):
+        toks[:, t] = cur
+        jump = rng.random(num_sequences) < noise
+        cur = np.where(jump, rng.integers(0, vocab, size=num_sequences), successor[cur])
+    return toks
+
+
+def round_batch(traffic: dict, num_agents: int, vocab: int, seed: int, rnd: int) -> dict:
+    """The global batch of round ``rnd``: ``sequences_per_agent`` rows per
+    agent, agent-major (the rows of agent a are a*spa .. (a+1)*spa - 1), and
+    a participation mask over the rows. Each agent sits the round out with
+    probability ``absent_prob``, independently; a round in which none is
+    present is drawn again. Every round has the same sizes, whatever the
+    seed."""
+    spa = traffic["sequences_per_agent"]
+    rng = np.random.default_rng([seed, rnd])
+    present = np.zeros(num_agents, bool)
+    while not present.any():
+        present = rng.random(num_agents) >= traffic["absent_prob"]
+    toks = markov_tokens(num_agents * spa, traffic["seq_len"], vocab, traffic["token_noise"], rng)
+    return {
+        "tokens": toks,
+        "participation": np.repeat(present, spa).astype(np.float32),
+    }
