@@ -40,14 +40,15 @@ def arch_config(config: dict):
 
 
 def _check_layout(model, config: dict) -> None:
-    """The program's parameters are the leaves the weights are made for."""
-    want = {k: s for k, (s, _) in ref.model_ref(config).leaf_shapes(config["model"]).items()}
+    """The program's parameters are the leaves the weights are made for, and
+    the model reference's own ``check``, where it has one, passes."""
+    mref = ref.model_ref(config)
+    want = {k: s for k, (s, _) in mref.leaf_shapes(config["model"]).items()}
     got = {k: tuple(v.shape) for k, v in ref.flatten(model.param_shapes()).items()}
     if got != want:
         raise ValueError(f"{config['arch']}: parameters {got} are not the configuration's {want}")
-    attn = model.cfg.groups[0].blocks[0].attn
-    if attn.rope_theta != config["model"]["rope_theta"] or model.cfg.tie_embeddings:
-        raise ValueError(f"{config['arch']}: rope or head differ from the configuration")
+    if hasattr(mref, "check"):
+        mref.check(model.cfg, config["model"])
 
 
 def _leaf_layer_norms(tree: dict) -> dict:

@@ -75,8 +75,10 @@ def ffn_forward_flops(s, seq_len: int) -> int:
 def moe_forward_flops(s, seq_len: int) -> int:
     """A routed MoE (``MoESpec``): the router over all experts, ``top_k``
     gated experts of width ``d_expert`` per token, and the shared experts
-    (one gated MLP of total width ``d_shared``). Capacity padding and
-    dropped tokens are not work the model requires."""
+    (one gated MLP of total width ``d_shared``). This is the work the model
+    requires, whatever share of the experts a chip holds: ``mfu`` divides
+    it by every chip's peak. Capacity padding and dropped tokens are not
+    work the model requires."""
     d = s.d_model
     per_token = 2 * d * s.num_experts + s.top_k * 3 * 2 * d * s.d_expert
     if s.num_shared > 0:

@@ -49,6 +49,14 @@ def leaf_shapes(model: dict) -> Dict[str, tuple]:
     }
 
 
+def check(arch, model: dict) -> None:
+    """The program's ``ArchConfig`` has the configuration's rope theta and
+    an untied head, which its leaves do not show."""
+    attn = arch.groups[0].blocks[0].attn
+    if attn.rope_theta != model["rope_theta"] or arch.tie_embeddings:
+        raise ValueError(f"{arch.name}: rope or head differ from the configuration")
+
+
 def groups(model: dict):
     """[(group prefix, layers in it, {weight: leaf})], in order."""
     return [("g0", model["num_hidden_layers"], LAYER)]

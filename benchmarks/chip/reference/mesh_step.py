@@ -30,6 +30,32 @@ moments fit on four chips, and no device's work leaves it.
 ``Variant`` plants a fault in that round, or computes its matrix products
 in a lower precision than the configuration states (the control); the
 benchmark's runs use the plain one.
+
+A model reference is a module here that gives, for the configuration's
+``model`` dict:
+
+  EMBED, FINAL_NORM, HEAD   the names of the embedding table, the final
+                            norm's weight and the output head's table
+  leaf_shapes(model)        {leaf name: (shape, std)} in the program's
+                            parameter layout, the layers of a group stacked
+                            on a leading axis under the group's prefix
+  groups(model)             [(group prefix, layers in it, {weight: leaf})]
+  layer_fwd(group, w, x, model, fp8)
+                            one layer of the group on one row ``x`` (S, d):
+                            the new ``x``, or ``(x, a)`` where ``a`` is a
+                            float32 scalar added to the row's loss as it is
+                            (an auxiliary loss, weighted by the model
+                            reference itself); ``check_layers`` refuses
+                            any other output when the programs are built
+  loss(fnorm, table, x, tokens, model, fp8)
+                            the row's loss through the final norm and head
+  check(arch, model)        optional: raises where the program's
+                            ``ArchConfig`` departs from the configuration in
+                            what its leaves do not show
+
+A row's loss is the head's loss plus every ``a`` of its layers; the
+participation weights mask it whole, so an absent agent's row adds nothing
+to the loss or to a gradient.
 """
 from __future__ import annotations
 
@@ -118,12 +144,33 @@ def _highest(fn):
     return wrapped
 
 
+def check_layers(mref, model: dict, fp8: bool) -> None:
+    """Each group's ``layer_fwd`` keeps to the contract: it returns ``x``,
+    float32 of the input's shape, or ``(x, a)`` with ``a`` a float32 scalar.
+    Anything else would enter the row's loss and its backward unseen.
+    Traced on shapes alone, on a row of 8 positions."""
+    shapes = mref.leaf_shapes(model)
+    x = jax.ShapeDtypeStruct((8, shapes[mref.EMBED][0][1]), F32)
+
+    def f32(v, shape) -> bool:
+        return isinstance(v, jax.ShapeDtypeStruct) and v.shape == shape and v.dtype == F32
+
+    for g, (prefix, _, keys) in enumerate(mref.groups(model)):
+        w = {k: jax.ShapeDtypeStruct(shapes[name][0][1:], BF16) for k, name in keys.items()}
+        y = jax.eval_shape(lambda ww, xx: mref.layer_fwd(g, ww, xx, model, fp8), w, x)
+        pair = isinstance(y, tuple) and len(y) == 2 and f32(y[0], x.shape) and f32(y[1], ())
+        if not (f32(y, x.shape) or pair):
+            raise ValueError(f"layer_fwd of group {prefix} returns {y}: not x, float32 "
+                             f"{x.shape}, or (x, a) with a a float32 scalar")
+
+
 class RowPrograms:
     """The jitted programs of a round: rows sharded over the devices (one
     row's work on each), weights replicated, everything per row; one layer
     program per group of layers."""
 
     def __init__(self, mref, model: dict, devices, fp8: bool):
+        check_layers(mref, model, fp8)
         self.mesh = Mesh(np.asarray(devices), ("rows",))
         self.rows = NamedSharding(self.mesh, P("rows"))
         self.repl = NamedSharding(self.mesh, P())
@@ -134,8 +181,11 @@ class RowPrograms:
 
         def bwd(g, w, x, dy):
             def one(xr, dyr):
-                _, vjp = jax.vjp(lambda ww, xx: mref.layer_fwd(g, ww, xx, m, fp8), w, xr)
-                return vjp(dyr)
+                y, vjp = jax.vjp(lambda ww, xx: mref.layer_fwd(g, ww, xx, m, fp8), w, xr)
+                # x's cotangent dy; an ``a`` enters the row's loss as it is: 1
+                _, *a = jax.tree.leaves(y)
+                return vjp(jax.tree.unflatten(jax.tree.structure(y),
+                                              [dyr] + [jnp.ones_like(v) for v in a]))
             dw, dx = jax.vmap(one)(x, dy)
             return dx, dw
 
@@ -376,9 +426,9 @@ def _delta(a, b):
 
 def _row_grads(prog: RowPrograms, mref, model: dict, params: dict, owner: dict,
                tokens: np.ndarray, wp: np.ndarray, part):
-    """Each row's loss and gradient at ``params``, one row per device,
-    combined into each partition's mean by ``wp`` and kept on the unit's
-    owner; and the rows' losses."""
+    """Each row's loss (the head's and its layers' ``a``) and gradient at
+    ``params``, one row per device, combined into each partition's mean by
+    ``wp`` and kept on the unit's owner; and the rows' losses."""
     repl, rows = prog.repl, prog.rows
     tok = jax.device_put(np.asarray(tokens, np.int32), rows)
     wpd = jax.device_put(wp, repl)
@@ -399,11 +449,13 @@ def _row_grads(prog: RowPrograms, mref, model: dict, params: dict, owner: dict,
         return {k: everywhere(f"{name}#{l}") for k, name in keys.items()}
 
     x = prog.embed(everywhere(mref.EMBED), tok)
-    xs = []
+    xs, aux = [], []
     for g, l, keys in layers:
         xs.append(x)
-        x = prog.fwd[g](weights(keys, l), x)
+        x, *a = jax.tree.leaves(prog.fwd[g](weights(keys, l), x))
+        aux += a
     loss, (g_fn, g_head, dx) = prog.head(everywhere(mref.FINAL_NORM), everywhere(mref.HEAD), x, tok)
+    loss = np.asarray(loss, np.float64) + sum(np.asarray(a, np.float64) for a in aux)
     del x
     put(mref.FINAL_NORM, g_fn)
     put(mref.HEAD, g_head)
@@ -416,7 +468,7 @@ def _row_grads(prog: RowPrograms, mref, model: dict, params: dict, owner: dict,
             put(f"{name}#{l}", dw[k])
         del dw
     put(mref.EMBED, prog.embed_bwd(tok, dx))
-    return grads, np.asarray(loss, np.float64)
+    return grads, loss
 
 
 # ------------------------------------------------------------ compare ---

@@ -80,6 +80,27 @@ def test_mla_and_moe_lm_flops_by_hand():
     assert flops.lm_forward_flops(_arch("deepseek-v2-lite-16b"), S) == fwd
 
 
+def test_moe_flops_count_the_work_the_model_requires(monkeypatch):
+    # the count is the MoESpec's top_k experts a token, not what the program's
+    # parameters hold: with the layer's expert weights cut to 2 of the 8, the
+    # reduced deepseek-v2-lite still counts as in test_mla_and_moe_lm_flops_by_hand
+    import dataclasses
+
+    from repro.models import layers
+
+    real = layers.init_moe
+
+    def hold_two(s):
+        defs = real(s)
+        for k in ("wg", "wu", "wd"):
+            defs[k] = dataclasses.replace(defs[k], shape=(2,) + defs[k].shape[1:])
+        return defs
+
+    monkeypatch.setattr(layers, "init_moe", hold_two)
+    assert layers.init_moe(_arch("deepseek-v2-lite-16b").groups[1].blocks[1].moe)["wg"].shape[0] == 2
+    assert flops.lm_forward_flops(_arch("deepseek-v2-lite-16b"), 8) == 2_270_976
+
+
 def test_windowed_attention_counts_the_window():
     import dataclasses
 

@@ -74,3 +74,60 @@ def test_exits_nonzero_without_a_chip():
     assert proc.returncode != 0
     last = (proc.stdout.strip().splitlines() or [""])[-1]
     assert '"correct"' not in last
+
+
+# ------------------------------------------ the mesh entry's layout check ---
+@pytest.mark.parametrize("changed", [None, "g1/b1/moe/wg"])
+def test_layout_check_takes_an_mla_moe_program(monkeypatch, changed):
+    import types
+
+    from benchmarks.chip.entries import mesh_step
+    from benchmarks.chip.reference.mesh_step import flatten
+    from repro.configs.registry import build_model, get_config
+
+    model = build_model(get_config("deepseek-v2-lite-16b", reduced=True))
+    leaves = {k: (tuple(v.shape), 0.02) for k, v in flatten(model.param_shapes()).items()}
+    assert model.cfg.groups[0].blocks[0].attn is None  # MLA blocks have no AttnSpec
+    if changed:
+        shape = leaves[changed][0]
+        leaves[changed] = ((shape[0], shape[1] // 4) + shape[2:], 0.02)  # 2 of 8 experts
+    # a model reference for this test only, with no check of its own
+    mref = types.ModuleType("benchmarks.chip.reference.mla_moe_leaves")
+    mref.leaf_shapes = lambda model: leaves
+    monkeypatch.setitem(sys.modules, mref.__name__, mref)
+    config = {"arch": "deepseek-v2-lite-16b", "reference_model": "mla_moe_leaves", "model": {}}
+    if changed:
+        with pytest.raises(ValueError, match="parameters"):
+            mesh_step._check_layout(model, config)
+    else:
+        mesh_step._check_layout(model, config)
+
+
+@pytest.mark.parametrize("changed", [None, "rope_theta", "tied_head"])
+def test_internlm2_check(changed):
+    import dataclasses
+
+    from benchmarks.chip.entries import mesh_step
+    from benchmarks.chip.reference import internlm2
+    from repro.configs import internlm2_1_8b
+    from repro.configs.registry import build_model
+
+    arch = internlm2_1_8b.reduced()
+    config = gen.load("configs", "internlm2-1.8b.data4")
+    config["model"].update(
+        num_hidden_layers=2, hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, intermediate_size=128, vocab_size=256, rope_theta=10000.0,
+    )
+    if changed == "rope_theta":
+        config["model"]["rope_theta"] = 1e6
+    if changed == "tied_head":
+        arch = dataclasses.replace(arch, tie_embeddings=True)
+    if changed is None:
+        internlm2.check(arch, config["model"])
+        mesh_step._check_layout(build_model(arch), config)
+        return
+    with pytest.raises(ValueError, match="rope or head"):
+        internlm2.check(arch, config["model"])
+    if changed == "rope_theta":  # a tied head has no lm_head leaf: the leaves differ first
+        with pytest.raises(ValueError, match="rope or head"):
+            mesh_step._check_layout(build_model(arch), config)
