@@ -60,9 +60,11 @@ Traffic accounting is computed in closed form (PERFECT) or by the mask
 stream (LOSSY) and matches the scalar engine's pubsub counters exactly.
 
 Multi-round fusion: with ``SimConfig(scan_rounds=W)`` the engine runs
-windows of W rounds as ONE ``lax.scan``-driven device call each. Batches,
-fate tensors (via the windowed batch draw ``MessageFates.draw_window``)
-and the host control plane are pre-drawn for the whole window; the device
+windows of W rounds as ONE ``lax.scan``-driven device call each. Batch
+row indices, fate tensors (via the windowed batch draw
+``MessageFates.draw_window``) and the host control plane are pre-drawn for
+the whole window; the images stay in a device-resident training set that
+the scanned body gathers each round's batches from; the device
 state — weights plane, cache plane, delta ring, value-history rings —
 lives in the fixed-shape scan carry, and the ring rotations/cache-event
 gathers run as in-carry dynamic indices inside the scanned body. Per-round
@@ -73,6 +75,7 @@ equal to the scalar pubsub counters. Evaluation is gated by
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Tuple
 
 import jax
@@ -129,6 +132,19 @@ def contributor_rows(D, idx, *, inst_of_k, offsets, sizes, S: int):
         Gk = Dk[idx[int(rows[0]) : int(rows[-1]) + 1]]
         parts.append(jnp.pad(Gk, ((0, 0), (0, 0), (0, S - int(s)))))
     return jnp.concatenate(parts, axis=0)
+
+
+def gather_batches(x_tab, y_tab, idx):
+    """Each batch bucket's (rows, bs, ...) images and labels, gathered from
+    the device-resident training set by the staged global row indices
+    ``idx`` (one (rows, bs) int32 array per bucket). Whole-row gathers of the
+    flat (n, 784) table: the host stages 4 bytes a sample, not 3,136."""
+    with jax.named_scope("batch_gather"):
+        take = lambda t, i: jnp.take(t, i, axis=0, mode="clip")
+        return (
+            tuple(take(x_tab, i) for i in idx),
+            tuple(take(y_tab, i) for i in idx),
+        )
 
 
 class _HarvestDeferred(Exception):
@@ -215,6 +231,12 @@ class VectorizedIPLSSimulation:
         # jitted-call counter: benchmarks report dispatches/round (the scan
         # path's whole point is driving this to 1/W)
         self.device_dispatches = 0
+        # bytes the host has handed the device in window and round staging
+        # (batch row indices + routing/control tables); the training set
+        # itself is uploaded at construction, or at a re-snapshot that
+        # brings a shard the table lacks, and is not counted
+        self.staged_bytes = 0
+        self._tab_off: dict = {}
         self._last_accs: np.ndarray | None = None
         self.x_test, self.y_test = x_test, y_test
         # exact init state + init-phase traffic via the scalar constructor
@@ -311,11 +333,12 @@ class VectorizedIPLSSimulation:
         self.messages_dropped = self.net.pubsub.messages_dropped
 
         # ---- trainers: the scalar constructor's LocalTrainer objects own
-        # the per-agent RNG streams; drawing batches through their
-        # draw_batch() keeps both engines' SGD inputs identical by
+        # the per-agent RNG streams; drawing batch rows through their
+        # draw_indices() keeps both engines' SGD inputs identical by
         # construction ----
         self._trainers = [seed_sim.trainers[a] for a in range(A)]
         self._act_trainers = self._trainers
+        self._act_off = self._train_table(self._trainers, self._act_trainers)
         bs = [min(cfg.batch_size, len(shards[a][0])) for a in range(A)]
         # contiguous buckets of equal batch size (array_split shard sizes
         # differ by at most one, so there are at most two)
@@ -482,11 +505,6 @@ class VectorizedIPLSSimulation:
 
         layout_t = tuple((name, tuple(shape)) for name, shape in layout)
 
-        def _one_delta(w, x, y):
-            # flat-space SGD (bit-identical to the tree scan: same GEMMs,
-            # same update order) — saves the per-agent tree<->vector passes
-            return w - mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
-
         use_kernel = self._use_kernel
         # instance rows grouped by partition for the masked-matmul path
         inst_of_k = [np.nonzero(self._inst_k == k)[0] for k in range(K)]
@@ -597,7 +615,9 @@ class VectorizedIPLSSimulation:
         def sgd_all(W, Xs, Ys):
             """All agents' local SGD on the (A, N) weight matrix; Xs/Ys are
             per-bucket stacked batches (a single bucket unless array_split
-            handed out two shard sizes)."""
+            handed out two shard sizes). Flat-space SGD, bit-identical to the
+            tree scan (same GEMMs, same update order), saves the per-agent
+            tree<->vector passes."""
             step = lambda w, x, y: mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
             if len(buckets) == 1:
                 return jax.vmap(step)(W, Xs[0], Ys[0])
@@ -607,26 +627,28 @@ class VectorizedIPLSSimulation:
             ]
             return jnp.concatenate(parts, axis=0)
 
-        def fused_round(V_pre, V_merged, eps, X, Y, t_prev, contrib_idx, contrib_mask, contrib_M, t_eval):
+        def fused_round(V_pre, V_merged, eps, x_tab, y_tab, I, t_prev, contrib_idx, contrib_mask, contrib_M, t_eval):
             """One whole training round in a single device call: rebuild all
-            agents' weights, run every agent's local SGD, aggregate every
+            agents' weights, gather their batches from the device-resident
+            training set, run every agent's local SGD, aggregate every
             partition instance, merge replicas, evaluate."""
             W = build_W(V_pre, V_merged, t_prev, A)
-            W2 = jax.vmap(lambda w, x, y: mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t))(W, X, Y)
+            W2 = sgd_all(W, *gather_batches(x_tab, y_tab, I))
             return round_core(V_merged, eps, W, W2, contrib_idx, contrib_mask, contrib_M, t_eval)
 
         def make_scan(gate_eval: bool):
             """The multi-round fused path: a window of W rounds as ONE
-            device call, `lax.scan` over per-round xs (batches + routing
-            tables), carry = the small value tables (V_pre, V_merged, eps).
-            The scanned body is exactly `fused_round`'s math, so any W
-            produces the same trajectory as W unscanned calls."""
+            device call, `lax.scan` over per-round xs (batch row indices +
+            routing tables), carry = the small value tables (V_pre,
+            V_merged, eps); the training set is an argument, gathered from
+            in the body. The scanned body is exactly `fused_round`'s math, so
+            any W produces the same trajectory as W unscanned calls."""
 
-            def body(carry, xs):
+            def body(x_tab, y_tab, carry, xs):
                 V_pre, V_merged, eps = carry
-                Xr, Yr, t_prev, idx, mask, M, t_eval, de = xs
+                Ir, t_prev, idx, mask, M, t_eval, de = xs
                 W = build_W(V_pre, V_merged, t_prev, A)
-                W2 = sgd_all(W, Xr, Yr)
+                W2 = sgd_all(W, *gather_batches(x_tab, y_tab, Ir))
                 V_pre2, V_m2, eps2 = agg_merge(V_merged, eps, W, W2, idx, mask, M)
                 if gate_eval:
                     accs = jax.lax.cond(
@@ -640,19 +662,16 @@ class VectorizedIPLSSimulation:
                     return (V_pre2, V_m2, eps2), (accs, metric_pair(W - W2, V_m2))
                 return (V_pre2, V_m2, eps2), accs
 
-            def scan_window(V_pre, V_merged, eps, xs_all):
-                carry, ys = jax.lax.scan(body, (V_pre, V_merged, eps), xs_all)
+            def scan_window(V_pre, V_merged, eps, x_tab, y_tab, xs_all):
+                carry, ys = jax.lax.scan(
+                    functools.partial(body, x_tab, y_tab), (V_pre, V_merged, eps), xs_all
+                )
                 return carry + (ys if tel else (ys,))
 
             return jax.jit(scan_window, donate_argnums=(0, 1, 2))
 
-        self._build_W_j = jax.jit(build_W, static_argnums=(3,))
-        self._round_core_j = jax.jit(round_core)
         self._fused_round = jax.jit(fused_round, donate_argnums=(0, 1, 2))
         self._scan_window_j = make_scan(self._eval_cadence > 1)
-        self._batched_deltas_keep = jax.jit(
-            lambda W, X, Y: jax.vmap(_one_delta)(W, X, Y)
-        )
         # routing tables cycle with the phase; upload to device once
         self._phase_tables = [
             (
@@ -945,14 +964,15 @@ class VectorizedIPLSSimulation:
 
         # ---- trainers / batch buckets / eval rows -------------------------
         # the scalar constructor's (and _apply_churn's) LocalTrainer objects
-        # own the per-agent RNG streams; drawing batches through their
-        # draw_batch() keeps both engines' SGD inputs identical. Only ACTIVE
-        # agents train — offline agents' streams freeze, like the scalar
-        # round skipping them.
+        # own the per-agent RNG streams; drawing batch rows through their
+        # draw_indices() keeps both engines' SGD inputs identical. Only
+        # ACTIVE agents train — offline agents' streams freeze, like the
+        # scalar round skipping them.
         self._trainers = [sim.trainers[a] for a in self._ids]
         self._act_trainers = [
             tr for tr, on in zip(self._trainers, act) if on
         ]
+        self._act_off = self._train_table(self._trainers, self._act_trainers)
         bs = [min(cfg.batch_size, len(tr.x)) for tr in self._act_trainers]
         self._buckets = []
         start = 0
@@ -1378,14 +1398,13 @@ class VectorizedIPLSSimulation:
         inst_k_j = jnp.asarray(self._inst_k)
         inst_owner_j = jnp.asarray(self._inst_owner)
         WNB = S // WBLOCK if int8 else 0
-        widx = jnp.asarray(self._widx)
-        widx_eval = jnp.asarray(self._widx[self._eval_idx])
+        widx_eval = self._widx[self._eval_idx]
         # active-row structures: SGD runs over ONLINE rows only; python-level
         # branches keep every jaxpr byte-identical to the fixed-membership
         # programs when the whole membership is online
         full_active = self._full_active
         act_idx_j = jnp.asarray(self._act_idx)
-        widx_act = widx if full_active else jnp.asarray(self._widx[self._act_idx])
+        widx_act = self._widx if full_active else self._widx[self._act_idx]
         act3 = jnp.asarray(self._act)[:, None, None]
         # span-constant mail plane (harvested in-flight reply/replica wire
         # values); appended to each gather table only when non-empty so
@@ -1397,11 +1416,40 @@ class VectorizedIPLSSimulation:
         x_te = jnp.asarray(self.x_test)
         y_te = jnp.asarray(self.y_test)
 
-        def build_W(V, C, idx):
+        def row_slices(idx):
+            """Static one-row slices that assemble the flat weights of the
+            rows of ``idx``, a (rows, K) index into [V; C as (A*K, S)] fixed
+            for the span (owners read their instance row of V, everyone else
+            their (row, k) cache slot): per partition, (plane, start, limit)
+            per row. A row gather of the (A*K, S) cache plane compiles to a
+            loop of one dynamic slice per row on a TPU, about six device ops
+            a row."""
+            plan = []
+            for k in range(K):
+                s, col = int(sizes[k]), []
+                for i in idx[:, k].tolist():
+                    if i < K_inst:
+                        col.append((0, (i, 0), (i + 1, s)))
+                    else:
+                        r, kk = divmod(i - K_inst, K)
+                        col.append((1, (r, kk, 0), (r + 1, kk + 1, s)))
+                plan.append(col)
+            return plan
+
+        plan_act, plan_eval = row_slices(widx_act), row_slices(widx_eval)
+
+        def build_W(V, C, plan):
             with jax.named_scope("weight_build"):
-                tbl = jnp.concatenate([V, C.reshape(A * K, S)], axis=0)
+                planes = (V, C)
                 return jnp.concatenate(
-                    [tbl[idx[:, k], : sizes[k]] for k in range(K)], axis=1
+                    [
+                        jnp.concatenate(
+                            [jax.lax.slice(planes[p], a, b).reshape(1, -1) for p, a, b in col],
+                            axis=0,
+                        )
+                        for col in plan
+                    ],
+                    axis=1,
                 )
 
         def pre(V, C, Vstart_hist, Vagg_hist, c0_mask, c0_src):
@@ -1425,7 +1473,7 @@ class VectorizedIPLSSimulation:
                     parts0.append(V_mail_j)
                 T0 = jnp.concatenate(parts0, axis=0)
                 C0 = jnp.where(c0_mask[:, :, None], T0[c0_src], C)
-            W = build_W(V, C0, widx_act)
+            W = build_W(V, C0, plan_act)
             return Vstart_new, C0, W
 
         def core_main(V, C0, D_now, ring, Vagg_hist, Vstart_new, E,
@@ -1561,7 +1609,7 @@ class VectorizedIPLSSimulation:
 
         def eval_lossy(V_new, C2):
             # evaluate the sub-sampled agents on end-of-round state
-            W_eval = build_W(V_new, C2, widx_eval)
+            W_eval = build_W(V_new, C2, plan_eval)
             return jax.vmap(
                 lambda w: mlp_mnist.evaluate(unflatten_params(w, layout), x_te, y_te)
             )(W_eval)
@@ -1612,12 +1660,12 @@ class VectorizedIPLSSimulation:
             plane, delta ring, value-history rings); the host control
             plane's per-round dense tensors ride as scan xs."""
 
-            def body(carry, xs):
+            def body(x_tab, y_tab, carry, xs):
                 V, C, ring, Vagg_hist, Vstart_hist, Eres = carry
-                (Xr, Yr, c0_mask, c0_src, msrc, eps, mmask, cnt,
+                (Ir, c0_mask, c0_src, msrc, eps, mmask, cnt,
                  c2_mask, c2_src, kidx, kmask, de) = xs
                 Vstart_new, C0, W = pre(V, C, Vstart_hist, Vagg_hist, c0_mask, c0_src)
-                W2 = sgd_all(W, Xr, Yr)
+                W2 = sgd_all(W, *gather_batches(x_tab, y_tab, Ir))
                 D_act = W - W2
                 V_new, C2, ring_new, Vagg_hist_new, E_new = core_main(
                     V, C0, expand_rows(D_act), ring, Vagg_hist, Vstart_new, Eres,
@@ -1636,9 +1684,10 @@ class VectorizedIPLSSimulation:
                     return carry_new, (accs, metric_pair(D_act, V_new))
                 return carry_new, accs
 
-            def scan_window(V, C, ring, Vagg_hist, Vstart_hist, Eres, xs_all):
+            def scan_window(V, C, ring, Vagg_hist, Vstart_hist, Eres, x_tab, y_tab, xs_all):
                 return jax.lax.scan(
-                    body, (V, C, ring, Vagg_hist, Vstart_hist, Eres), xs_all
+                    functools.partial(body, x_tab, y_tab),
+                    (V, C, ring, Vagg_hist, Vstart_hist, Eres), xs_all,
                 )
 
             return jax.jit(scan_window, donate_argnums=(0, 1, 2, 3, 4, 5))
@@ -1646,10 +1695,8 @@ class VectorizedIPLSSimulation:
         self._lossy_pre_j = jax.jit(pre, donate_argnums=(1,))
         self._lossy_core_j = jax.jit(core, donate_argnums=(0, 1, 3, 4, 6))
         self._scan_window_j = make_scan(self._eval_cadence > 1)
-        self._batched_deltas_keep = jax.jit(
-            lambda W, X, Y: jax.vmap(
-                lambda w, x, y: w - mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
-            )(W, X, Y)
+        self._sgd_deltas_j = jax.jit(
+            lambda W, x_tab, y_tab, I: W - sgd_all(W, *gather_batches(x_tab, y_tab, I))
         )
 
     def _push_cache_event(self, deliver_ctr, send_ctr, a, k, kind, src_round, inst):
@@ -1667,14 +1714,19 @@ class VectorizedIPLSSimulation:
         )
         self._seq += 1
 
-    def _control_round(self, rnd: int, wf: "_FateWindow | None" = None) -> dict:
+    def _control_round(self, r0: int, W: int = 1, wf: "_FateWindow | None" = None) -> list:
+        """Rounds r0 .. r0+W-1 of the host-side control plane, in order: one
+        call a window, so that a scan window runs its W control rounds up
+        front and stacks their dense tensors as `lax.scan` xs."""
+        return [self._control_one_round(r0 + w, wf) for w in range(W)]
+
+    def _control_one_round(self, rnd: int, wf: "_FateWindow | None" = None) -> dict:
         """One round of the host-side control plane: fate draws, queue-ring
         drains, fetch warm-up state machine, traffic counters. Pure
         integer/boolean numpy over the fixed-shape event space — no device
-        data — so a scan window can run it W times up front and stack the
-        resulting dense tensors as `lax.scan` xs. Returns the per-round
-        control tensors plus (msgs, drops, nbytes), which are exactly the
-        scalar pubsub's counters for the round by construction."""
+        data. Returns the per-round control tensors plus (msgs, drops,
+        nbytes), which are exactly the scalar pubsub's counters for the
+        round by construction."""
         from repro.fl.rounds import (
             CH_FETCH,
             CH_FETCH_REPLY,
@@ -1997,43 +2049,33 @@ class VectorizedIPLSSimulation:
     def _run_round_lossy(self, rnd: int) -> dict:
         pt = self._pt
         with pt.phase("control"):
-            ctl = self._control_round(rnd)
+            ctl, = self._control_round(rnd)
 
         # ---- device calls -------------------------------------------------
         with pt.phase("batches"):
-            xs, ys = self._draw_batches()
+            idx = self._draw_batches(1)
+        with pt.phase("stage"):
+            Is = self._stage_batches(a[0] for a in idx)
+            c = {key: self._put(ctl[key]) for key in (
+                "c0_mask", "c0_src", "msrc", "eps", "mmask", "cnt",
+                "c2_mask", "c2_src", "kidx", "kmask",
+            )}
         with pt.phase("device_pre"):
             Vstart_new, C0, W = self._lossy_pre_j(
                 self._Vl, self._C, self._Vstart_hist, self._Vagg_hist,
-                jnp.asarray(ctl["c0_mask"]), jnp.asarray(ctl["c0_src"]),
+                c["c0_mask"], c["c0_src"],
             )
             if pt.sync:
                 jax.block_until_ready(W)
         with pt.phase("device_sgd"):
-            if len(self._buckets) == 1:
-                D_now = self._batched_deltas_keep(
-                    W, jnp.asarray(np.stack(xs)), jnp.asarray(np.stack(ys))
-                )
-            else:
-                parts = [
-                    self._batched_deltas_keep(
-                        W[lo:hi],
-                        jnp.asarray(np.stack(xs[lo:hi])),
-                        jnp.asarray(np.stack(ys[lo:hi])),
-                    )
-                    for lo, hi, _ in self._buckets
-                ]
-                D_now = jnp.concatenate(parts, axis=0)
+            D_now = self._sgd_deltas_j(W, self._x_tab, self._y_tab, Is)
             if pt.sync:
                 jax.block_until_ready(D_now)
         with pt.phase("device_core"):
             out = self._lossy_core_j(
                 self._Vl, C0, D_now, self._ring, self._Vagg_hist,
-                Vstart_new, self._E, jnp.asarray(ctl["msrc"]),
-                jnp.asarray(ctl["eps"]),
-                jnp.asarray(ctl["mmask"]), jnp.asarray(ctl["cnt"]),
-                jnp.asarray(ctl["c2_mask"]), jnp.asarray(ctl["c2_src"]),
-                jnp.asarray(ctl["kidx"]), jnp.asarray(ctl["kmask"]),
+                Vstart_new, self._E, c["msrc"], c["eps"], c["mmask"], c["cnt"],
+                c["c2_mask"], c["c2_src"], c["kidx"], c["kmask"],
             )
             if pt.sync:
                 jax.block_until_ready(out)
@@ -2049,7 +2091,7 @@ class VectorizedIPLSSimulation:
                 self._E, accs,
             ) = out
         self._Vstart_hist = Vstart_new
-        self.device_dispatches += 2 + len(self._buckets)
+        self.device_dispatches += 3
 
         self.messages_sent += ctl["msgs"]
         self.messages_dropped += ctl["drops"]
@@ -2074,26 +2116,22 @@ class VectorizedIPLSSimulation:
                 self._rep_src_agent, self._rep_k, self._rep_dst_agent,
             )
         with pt.phase("control"):
-            ctls = [self._control_round(r0 + w, wf) for w in range(W)]
+            ctls = self._control_round(r0, W, wf)
         with pt.phase("batches"):
-            Xw, Yw = [], []
-            for _ in range(W):
-                xs, ys = self._draw_batches()
-                Xw.append(xs)
-                Yw.append(ys)
+            idx = self._draw_batches(W)
         with pt.phase("stage"):
-            Xs, Ys = self._stage_batches(Xw, Yw)
-            stack = lambda key: jnp.asarray(np.stack([c[key] for c in ctls]))
-            des = jnp.asarray([self._do_eval(r0 + w) for w in range(W)])
+            Is = self._stage_batches(idx)
+            stack = lambda key: self._put(np.stack([c[key] for c in ctls]))
+            des = self._put(np.asarray([self._do_eval(r0 + w) for w in range(W)]))
             xs_all = (
-                Xs, Ys, stack("c0_mask"), stack("c0_src"), stack("msrc"),
+                Is, stack("c0_mask"), stack("c0_src"), stack("msrc"),
                 stack("eps"), stack("mmask"), stack("cnt"), stack("c2_mask"),
                 stack("c2_src"), stack("kidx"), stack("kmask"), des,
             )
         with pt.phase("device_window"):
             carry, ys = self._scan_window_j(
                 self._Vl, self._C, self._ring, self._Vagg_hist,
-                self._Vstart_hist, self._E, xs_all,
+                self._Vstart_hist, self._E, self._x_tab, self._y_tab, xs_all,
             )
             if pt.sync:
                 jax.block_until_ready(ys)
@@ -2121,25 +2159,54 @@ class VectorizedIPLSSimulation:
                     self._emit_row(r0 + w, c["r_vec"], c["eps64"], mets[w])
 
     # -- one round ----------------------------------------------------------
-    def _stage_batches(self, Xw, Yw):
-        """A window's drawn batches as per-bucket (W, rows, ...) device
-        arrays, the scan xs' batch part."""
-        W = len(Xw)
-        stack = lambda B: tuple(
-            jnp.asarray(np.stack([np.stack(B[w][lo:hi]) for w in range(W)]))
-            for lo, hi, _ in self._buckets
+    def _train_table(self, trainers, act_trainers) -> np.ndarray:
+        """Keep the fleet's training set on the device as one flat table
+        (``_x_tab`` (n, 784), ``_y_tab`` (n,) in the labels' dtype), the
+        trainers' shards concatenated, and return each active trainer's row
+        offset into it. Uploaded at construction, and again only when a
+        re-snapshot meets a shard the table lacks (a joiner's own data)."""
+        shards = {}
+        for tr in trainers:
+            shards.setdefault((id(tr.x), id(tr.y)), (tr.x, tr.y))
+        if not shards.keys() <= self._tab_off.keys():
+            self._tab_off, n = {}, 0
+            for key, (x, _) in shards.items():
+                self._tab_off[key] = n
+                n += len(x)
+            # the shards stay referenced, so their ids stay unique
+            self._tab_shards = list(shards.values())
+            self._x_tab = jnp.asarray(np.concatenate([x for x, _ in self._tab_shards]))
+            self._y_tab = jnp.asarray(np.concatenate([y for _, y in self._tab_shards]))
+        return np.asarray(
+            [self._tab_off[(id(tr.x), id(tr.y))] for tr in act_trainers], np.int64
         )
-        return stack(Xw), stack(Yw)
 
-    def _draw_batches(self):
-        # only the ONLINE agents' RNG streams advance — the scalar train
-        # phase skips offline agents, so their trainers must not draw
-        xs, ys = [], []
-        for tr in self._act_trainers:
-            xb, yb = tr.draw_batch()
-            xs.append(xb)
-            ys.append(yb)
-        return xs, ys
+    def _put(self, a) -> jax.Array:
+        """Hand one staged host array to the device, counting its bytes."""
+        out = jnp.asarray(a)
+        self.staged_bytes += out.nbytes
+        return out
+
+    def _stage_batches(self, idx):
+        """Drawn batch row indices as device arrays, one per batch bucket:
+        the scan xs' batch part (the images stay on the device)."""
+        return tuple(self._put(a) for a in idx)
+
+    def _draw_batches(self, W: int):
+        """W rounds of every online agent's batch as global row indices into
+        the device-resident training set: one (W, rows, bs) int32 array per
+        batch bucket. Round-major, so each trainer's stream advances exactly
+        as in W single rounds. Only the ONLINE agents' RNG streams advance —
+        the scalar train phase skips offline agents, so their trainers must
+        not draw."""
+        rounds = [
+            [off + tr.draw_indices() for tr, off in zip(self._act_trainers, self._act_off)]
+            for _ in range(W)
+        ]
+        return [
+            np.asarray([I[lo:hi] for I in rounds], np.int32)
+            for lo, hi, _ in self._buckets
+        ]
 
     def _scalar_round(self, rnd: int) -> dict:
         """One round on the embedded scalar oracle: membership-event rounds
@@ -2166,35 +2233,18 @@ class VectorizedIPLSSimulation:
             return self._run_round_lossy(rnd)
         pt = self._pt
         with pt.phase("batches"):
-            xs, ys = self._draw_batches()
+            idx = self._draw_batches(1)
+        with pt.phase("stage"):
+            Is = self._stage_batches(a[0] for a in idx)
         p = rnd % self._period
         p_prev = self._last_phase
         idx, mask, M, t_inst, t_eval = self._phase_tables[p]
         t_prev = self._phase_tables[p_prev][3]
         with pt.phase("device_round"):
-            if len(self._buckets) == 1:
-                out = self._fused_round(
-                    self._V_pre, self._V_merged, self._eps,
-                    jnp.asarray(np.stack(xs)), jnp.asarray(np.stack(ys)),
-                    t_prev, idx, mask, M, t_eval,
-                )
-            else:
-                # heterogeneous batch sizes (at most two contiguous buckets
-                # from array_split): assemble weights once, SGD per bucket,
-                # then the shared aggregation/eval core
-                W = self._build_W_j(self._V_pre, self._V_merged, t_prev, self.A)
-                parts = [
-                    self._batched_deltas_keep(
-                        W[lo:hi],
-                        jnp.asarray(np.stack(xs[lo:hi])),
-                        jnp.asarray(np.stack(ys[lo:hi])),
-                    )
-                    for lo, hi, _ in self._buckets
-                ]
-                W2 = W - jnp.concatenate(parts, axis=0)
-                out = self._round_core_j(
-                    self._V_merged, self._eps, W, W2, idx, mask, M, t_eval
-                )
+            out = self._fused_round(
+                self._V_pre, self._V_merged, self._eps, self._x_tab, self._y_tab,
+                Is, t_prev, idx, mask, M, t_eval,
+            )
             if pt.sync:
                 jax.block_until_ready(out)
         met = None
@@ -2202,7 +2252,7 @@ class VectorizedIPLSSimulation:
             self._V_pre, self._V_merged, self._eps, accs, met = out
         else:
             self._V_pre, self._V_merged, self._eps, accs = out
-        self.device_dispatches += 1 if len(self._buckets) == 1 else 2 + len(self._buckets)
+        self.device_dispatches += 1
         self._last_phase = p
         accs = np.asarray(accs, np.float32)
 
@@ -2297,21 +2347,14 @@ class VectorizedIPLSSimulation:
         }
 
     def _run_window_perfect(self, r0: int, W: int) -> None:
-        """W PERFECT rounds as one lax.scan device call: batches and the
-        phase-cycled routing tables are stacked as (W, ...) scan xs, the
-        value tables (V_pre, V_merged, eps) ride the carry."""
-        # pre-draw the whole window's batches through the trainers' rng
-        # streams — round-major order, so the streams advance exactly as in
-        # the unscanned path
+        """W PERFECT rounds as one lax.scan device call: batch row indices
+        and the phase-cycled routing tables are stacked as (W, ...) scan xs,
+        the value tables (V_pre, V_merged, eps) ride the carry."""
         pt = self._pt
         with pt.phase("batches"):
-            Xw, Yw = [], []
-            for _ in range(W):
-                xs, ys = self._draw_batches()
-                Xw.append(xs)
-                Yw.append(ys)
+            idx = self._draw_batches(W)
         with pt.phase("stage"):
-            Xs, Ys = self._stage_batches(Xw, Yw)
+            Is = self._stage_batches(idx)
             prev = self._last_phase
             t_prev_l, idx_l, mask_l, M_l, t_eval_l, de_l = [], [], [], [], [], []
             for w in range(W):
@@ -2324,15 +2367,17 @@ class VectorizedIPLSSimulation:
                 t_eval_l.append(self._t_inst[p][self._eval_idx])
                 de_l.append(self._do_eval(rnd))
                 prev = p
+            put = self._put
             xs_all = (
-                Xs, Ys,
-                jnp.asarray(np.stack(t_prev_l)), jnp.asarray(np.stack(idx_l)),
-                jnp.asarray(np.stack(mask_l)), jnp.asarray(np.stack(M_l)),
-                jnp.asarray(np.stack(t_eval_l)), jnp.asarray(np.asarray(de_l, bool)),
+                Is,
+                put(np.stack(t_prev_l)), put(np.stack(idx_l)),
+                put(np.stack(mask_l)), put(np.stack(M_l)),
+                put(np.stack(t_eval_l)), put(np.asarray(de_l, bool)),
             )
         with pt.phase("device_window"):
             out = self._scan_window_j(
-                self._V_pre, self._V_merged, self._eps, xs_all
+                self._V_pre, self._V_merged, self._eps, self._x_tab, self._y_tab,
+                xs_all,
             )
             if pt.sync:
                 jax.block_until_ready(out)

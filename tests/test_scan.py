@@ -9,6 +9,8 @@ counters. Eval can additionally be thinned to a cadence without perturbing
 the training trajectory.
 """
 
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -154,3 +156,79 @@ def test_scalar_engine_ignores_scan_rounds(data):
     w_b = np.stack([sim_b.agents[a].load_model() for a in range(4)])
     np.testing.assert_array_equal(w_a, w_b)
     assert [m["bytes_total"] for m in hist_a] == [m["bytes_total"] for m in hist_b]
+
+
+# Output digests of the fused engine, pinned on the CPU before the window's
+# batches moved from host-staged images to a device-resident training set
+# gathered by staged row indices. The move changes where the copy happens,
+# not what SGD sees: weights, accuracies and traffic counters stay equal to
+# the bit, scanned (W=4) and per round (W=0).
+def _join_shard(agent_id):
+    # a joiner's own 100-row shard: one the training table lacks, and a
+    # second batch bucket (100 rows < batch size 128)
+    x, y, _, _ = synth_mnist(num_train=100, num_test=10, seed=agent_id)
+    return x, y
+
+
+PINNED_CASES = {
+    "perfect-f32": dict(num_agents=5, num_partitions=8, pi=2, rho=2),
+    "lossy-int8": dict(
+        num_agents=5, num_partitions=8, pi=2, rho=2, conditions=LOSSY, seed=1,
+        wire_dtype="int8",
+    ),
+    # 1500 rows over 13 agents: shards of 115 and 116, two batch buckets
+    "two-bucket": dict(num_agents=13, num_partitions=8, pi=2, rho=2),
+    # the offline/online/leave re-snapshots keep the training table; the
+    # join brings a shard it lacks
+    "lossy-churn": dict(
+        num_agents=5, num_partitions=6, pi=2, rho=2, conditions=LOSSY, seed=0,
+        memory=True, join_shard=_join_shard,
+        churn={1: [(2, "offline")], 3: [(4, "leave"), (2, "online")], 4: [(5, "join")]},
+    ),
+}
+PINNED = {
+    "perfect-f32": "f7df1cbfd3a6bf4296d09cf6a2403995cc8b0720d39b115b02148cfba197b55c",
+    "lossy-int8": "4d280669be0c33edc96d38681272c296881a331a74e0c1ae128234d7f1dd75f8",
+    "two-bucket": "81851b4edef0d71b40d87c8d2beab703f654857609423bc0c887381006c81669",
+    "lossy-churn": "c026ebf1f916e968c8d2b1d5885e767cab93693cc713d3306a7892146c40459f",
+}
+
+
+def _output_digest(sim, hist) -> str:
+    h = hashlib.sha256(np.ascontiguousarray(sim.agent_weights(), np.float32).tobytes())
+    for m in hist:
+        h.update(repr(tuple(m[k] for k in (
+            "round", "active", "bytes_total", "acc_mean", "acc_std", "acc_max"
+        ))).encode())
+    h.update(repr((sim.messages_sent, sim.messages_dropped)).encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("scan", [0, 4])
+@pytest.mark.parametrize("case", list(PINNED_CASES))
+def test_fused_outputs_pinned_to_the_bit(data, case, scan):
+    sim, hist = _run(data, "vectorized", scan, **PINNED_CASES[case])
+    assert _output_digest(sim, hist) == PINNED[case]
+
+
+@pytest.mark.parametrize("net", NETS)
+def test_staged_bytes_counts_indices_not_images(data, net):
+    """A window stages each agent's batch as int32 row indices, plus the
+    routing (PERFECT) or control (LOSSY) tables; the images stay on the
+    device. At this fleet that is below one agent-round of images a round."""
+    W, A, bs = 4, 5, 128
+    sim, _ = _run(
+        data, "vectorized", W, rounds=W, num_agents=A, num_partitions=8, pi=2,
+        rho=2, **net,
+    )
+    index_bytes = W * A * bs * 4
+    assert index_bytes <= sim.staged_bytes < W * bs * 784 * 4
+    if not net:
+        # PERFECT: indices + the phase-cycled routing tables + the eval gates
+        per_round = sum(
+            t.nbytes for t in (
+                sim._t_inst[0], sim._contrib_idx[0], sim._contrib_mask[0],
+                sim._contrib_M[0], sim._t_inst[0][sim._eval_idx],
+            )
+        ) + 1
+        assert sim.staged_bytes == index_bytes + W * per_round

@@ -155,6 +155,7 @@ def _window_program(conditions, wire_dtype, sharding):
 
 
 _window_texts: dict = {}
+_window_args: dict = {}
 
 
 def _window_text(path, sharding) -> str:
@@ -168,6 +169,7 @@ def _window_text(path, sharding) -> str:
         conditions, wire_dtype = path.split("-")
         conditions = {"perfect": PERFECT, "lossy": LOSSY}[conditions]
         fn, args = _window_program(conditions, wire_dtype, sharding)
+        _window_args[path] = args
         try:
             with pytest.MonkeyPatch.context() as mp:
                 # the kernel picks its mode from the default backend (the CPU here)
@@ -214,3 +216,38 @@ def test_contrib_gather_moves_whole_rows(one_chip, path):
     for line in gathers:
         sizes = re.search(r"slice_sizes=\{([^}]*)\}", line).group(1).split(",")
         assert int(sizes[-1]) > 1, line
+
+
+@pytest.mark.parametrize("path", ["perfect-f32", "lossy-int8"])
+def test_window_program_gathers_batches_on_device(one_chip, path):
+    """The window program takes the fleet's training set as one flat
+    (n, 784) table and each round's batches as int32 (W, rows, bs) row
+    indices: no (W, rows, bs, 784) image input. The gather keeps its
+    `batch_gather` scope into op_name and moves whole 784-wide rows."""
+    text = _window_text(path, one_chip)
+    leaves = jax.tree.leaves(_window_args[path])
+    # the tiny fleet: 4 agents of 100 rows, batch 32, windows of 2 rounds
+    assert any(a.shape == (400, 784) and a.dtype == jnp.float32 for a in leaves)
+    assert any(a.shape == (2, 4, 32) and a.dtype == jnp.int32 for a in leaves)
+    assert not any(a.ndim == 4 and a.shape[-1] == 784 for a in leaves)
+    gathers = [
+        line for line in text.splitlines()
+        if " gather(" in line and "/batch_gather/" in line
+    ]
+    sizes = {re.search(r"slice_sizes=\{([^}]*)\}", line).group(1) for line in gathers}
+    # whole image rows and whole labels, nothing element-indexed
+    assert "1,784" in sizes and sizes <= {"1,784", "1"}, sizes
+
+
+@pytest.mark.parametrize("path", ["lossy-int8", "lossy-f32"])
+def test_weight_build_compiles_to_no_loop(one_chip, path):
+    """The LOSSY path assembles the agents' weights without a per-row loop:
+    on a TPU a row gather of its (A*K, S) cache plane compiled to one loop
+    of dynamic slices per partition, about six device ops a row each
+    round."""
+    text = _window_text(path, one_chip)
+    loops = [
+        line for line in text.splitlines()
+        if " while(" in line and "/weight_build/" in line
+    ]
+    assert not loops, loops[:1]

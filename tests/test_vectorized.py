@@ -55,6 +55,8 @@ def _assert_equivalent(sim_s, hist_s, sim_v, hist_v, num_agents, atol_w=1e-4):
         # more agents than partition slots: some agents own nothing
         dict(num_agents=10, num_partitions=6, pi=2, rho=2, eval_agents=3),
         dict(num_agents=6, num_partitions=5, pi=2, rho=3),
+        # 1500 rows over 13 agents: shards of 115 and 116, two batch buckets
+        dict(num_agents=13, num_partitions=8, pi=2, rho=2),
     ],
 )
 def test_engines_equivalent_under_perfect(data, kw):
@@ -327,3 +329,49 @@ def test_churn_rho1_crash_reassignment_matches(data):
     ids = [a for a, ag in sim_s.agents.items() if ag.live]
     w_s = np.stack([sim_s.agents[a].load_model() for a in ids])
     np.testing.assert_allclose(w_s, sim_v.agent_weights(), atol=3e-8)
+
+
+def test_draw_indices_selects_draw_batch_rows(data):
+    """`draw_indices` advances the trainer's stream exactly as `draw_batch`
+    does, and indexing the shard with it gives `draw_batch`'s arrays."""
+    from repro.fl.local_trainer import LocalTrainer
+
+    x, y = data[0][:300], data[1][:300]
+    a = LocalTrainer(3, x, y, seed=7)
+    b = LocalTrainer(3, x, y, seed=7)
+    for _ in range(5):
+        sel = a.draw_indices()
+        xb, yb = b.draw_batch()
+        np.testing.assert_array_equal(x[sel], xb)
+        np.testing.assert_array_equal(y[sel], yb)
+    assert a._rng.bit_generator.state == b._rng.bit_generator.state
+
+
+@pytest.mark.parametrize("scan", [0, 3])
+@pytest.mark.parametrize(
+    "kw",
+    [
+        dict(num_agents=13, num_partitions=8, pi=2, rho=2),
+        dict(num_agents=5, num_partitions=6, pi=2, rho=2, conditions=LOSSY,
+             churn=CHURN_ALL_ACTIONS, memory=True),
+    ],
+    ids=["perfect-two-bucket", "lossy-churn"],
+)
+def test_fused_engine_advances_trainer_streams_like_scalar(data, scan, kw):
+    """The fused engine draws batch rows through the trainers' own streams:
+    after a run every trainer's RNG state equals the scalar engine's (offline
+    agents' streams frozen alike)."""
+    x_tr, y_tr, x_te, y_te = data
+    cfg = SimConfig(rounds=7, local_iters=1, **kw)
+    shards = iid_split(x_tr, y_tr, cfg.num_agents, seed=0)
+    sim_s = IPLSSimulation(cfg, shards, x_te, y_te)
+    sim_s.run()
+    sim_v = make_simulation(
+        dataclasses.replace(cfg, engine="vectorized", scan_rounds=scan),
+        shards, x_te, y_te,
+    )
+    sim_v.run()
+    trainers_v = sim_v._seed.trainers
+    assert sorted(trainers_v) == sorted(sim_s.trainers)
+    for a, tr in sim_s.trainers.items():
+        assert trainers_v[a]._rng.bit_generator.state == tr._rng.bit_generator.state, a
