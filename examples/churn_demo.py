@@ -27,7 +27,8 @@ def main():
     )
     ap.add_argument(
         "--scan-rounds", type=int, default=0,
-        help="vectorized only: fuse this many rounds per lax.scan device call",
+        help="vectorized only: fuse this many rounds per lax.scan device call "
+        "(0: one round a call)",
     )
     ap.add_argument(
         "--telemetry", action="store_true",

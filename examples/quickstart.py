@@ -34,7 +34,8 @@ def main():
     )
     ap.add_argument(
         "--scan-rounds", type=int, default=0,
-        help="vectorized only: fuse this many rounds per lax.scan device call",
+        help="vectorized only: fuse this many rounds per lax.scan device call "
+        "(0: one round a call)",
     )
     ap.add_argument(
         "--wire-dtype", default="f32", choices=["f32", "int8"],

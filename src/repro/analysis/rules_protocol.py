@@ -59,7 +59,6 @@ SYMMETRY: Dict[str, Dict[str, Set[str]]] = {
     # between the oracle and the dense planes (docs/ENGINE.md "Churn
     # re-snapshot") — they mirror the scalar tick's delivery accounting
     "fl/vectorized.py": {
-        "_run_round_lossy": {"messages_sent", "messages_dropped", "_bytes_total"},
         "_run_window_lossy": {"messages_sent", "messages_dropped", "_bytes_total"},
         "_perfect_traffic": {"messages_sent", "_bytes_total"},
         "_init_lossy": {"bytes_recv"},

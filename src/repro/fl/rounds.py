@@ -147,15 +147,17 @@ class SimConfig:
     # rounds replay on the embedded scalar oracle and the dense planes are
     # re-snapshotted at the boundary; see fl/vectorized.py and docs/ENGINE.md)
     engine: str = "scalar"
-    # multi-round fusion (vectorized engine only): 0 = one device call per
-    # round; W >= 1 = run windows of W rounds as ONE lax.scan-driven device
-    # call each, with batches / fate tensors / routing tables pre-drawn for
-    # the whole window (see docs/ENGINE.md "Multi-round fused scan")
+    # multi-round fusion (vectorized engine only): W >= 1 = run windows of W
+    # rounds as ONE lax.scan-driven device call each, with batches / fate
+    # tensors / routing tables pre-drawn for the whole window; 0 = one-round
+    # windows of the same program (see docs/ENGINE.md "Multi-round fused
+    # scan")
     scan_rounds: int = 0
-    # scanned-mode evaluation cadence: evaluate every `eval_cadence`-th round
-    # (plus the final round); skipped rounds reuse the last computed accuracy
-    # in the history. 1 (default) evaluates every round, so accuracy traces
-    # are identical to the unscanned engines.
+    # scanned-mode evaluation cadence (scan_rounds >= 1 only; scan_rounds=0
+    # evaluates every round): evaluate every `eval_cadence`-th round (plus
+    # the final round); skipped rounds reuse the last computed accuracy in
+    # the history. 1 (default) evaluates every round, so accuracy traces are
+    # identical for any window size.
     eval_cadence: int = 1
     # data shard for agents added by a "join" churn action: a callable
     # agent_id -> (x, y). None = round-robin over the initial shards.
@@ -166,7 +168,8 @@ class SimConfig:
     wire_dtype: str = "f32"
     # observability (repro.telemetry, docs/TELEMETRY.md): telemetry=True
     # attaches a MetricsRecorder emitting one schema-ordered row per round —
-    # byte-for-byte identical across engines — plus per-phase wall timers;
+    # byte-for-byte identical across engines but for the two f32 norm
+    # columns, which agree within 2 ULP — plus per-phase wall timers;
     # trace=True additionally records a Chrome trace-event timeline
     # (protocol sends/deliveries/drops on simulated ticks + host phase
     # spans). Both default off: the disabled path adds no device outputs

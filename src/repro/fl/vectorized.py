@@ -13,8 +13,8 @@ that batches the three round phases:
   2. aggregation of every (partition, replica-slot) instance: on TPU one
      partition-batched Pallas launch (`kernels/ipls_aggregate`) with deltas
      laid out (K_inst, R, S) + a per-instance (mask, r, eps) table; on
-     CPU/GPU the identical math as K masked matmuls M @ (W - W2) that never
-     materialize the delta stacks — followed by replica consensus
+     CPU/GPU the same contributor rows reduced by a sequential masked sum
+     in the scalar oracle's pending order — followed by replica consensus
      (segment mean);
   3. evaluation of the (sub-sampled) agents in one vmapped call.
 
@@ -59,8 +59,10 @@ messages on exit. See docs/ENGINE.md "Churn re-snapshot".
 Traffic accounting is computed in closed form (PERFECT) or by the mask
 stream (LOSSY) and matches the scalar engine's pubsub counters exactly.
 
-Multi-round fusion: with ``SimConfig(scan_rounds=W)`` the engine runs
-windows of W rounds as ONE ``lax.scan``-driven device call each. Batch
+Multi-round fusion: each path (PERFECT, LOSSY) has exactly one device
+program, its scanned window. With ``SimConfig(scan_rounds=W)`` the engine
+runs windows of W rounds as ONE ``lax.scan``-driven device call each;
+``scan_rounds=0`` and ``run_round`` run one-round windows. Batch
 row indices, fate tensors (via the windowed batch draw
 ``MessageFates.draw_window``) and the host control plane are pre-drawn for
 the whole window; the images stay in a device-resident training set that
@@ -69,9 +71,9 @@ state — weights plane, cache plane, delta ring, value-history rings —
 lives in the fixed-shape scan carry, and the ring rotations/cache-event
 gathers run as in-carry dynamic indices inside the scanned body. Per-round
 bytes/messages/drops come back as stacked per-round values, still exactly
-equal to the scalar pubsub counters. Evaluation is gated by
-``eval_cadence`` so it can move to window boundaries. See docs/ENGINE.md
-"Multi-round fused scan".
+equal to the scalar pubsub counters. With ``scan_rounds >= 1``,
+evaluation is gated by ``eval_cadence`` so it can move to window
+boundaries. See docs/ENGINE.md "Multi-round fused scan".
 """
 from __future__ import annotations
 
@@ -89,7 +91,7 @@ from repro.kernels.ipls_aggregate.ops import aggregate_batched, aggregate_batche
 from repro.models import mlp_mnist
 from repro.telemetry.device import metric_pair
 
-# cache-event value sources (see _run_round_lossy)
+# cache-event value sources (see _control_one_round)
 _KIND_START = 0  # holder value at the start of the serve round (fetch reply)
 _KIND_AGG = 1  # holder value after aggregation, pre-merge (UpdateModel reply)
 _KIND_MAIL = 2  # harvested in-flight reply payload (span-constant mail plane)
@@ -147,6 +149,28 @@ def gather_batches(x_tab, y_tab, idx):
         )
 
 
+def sgd_all(W, Xs, Ys, *, buckets, lr, iters, layout_t):
+    """All rows' local SGD on the (rows, N) weight matrix; Xs/Ys are
+    per-bucket stacked batches (a single bucket unless array_split handed
+    out two shard sizes). Flat-space SGD, bit-identical to the tree scan
+    (same GEMMs, same update order), saves the per-agent tree<->vector
+    passes."""
+    step = lambda w, x, y: mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
+    if len(buckets) == 1:
+        return jax.vmap(step)(W, Xs[0], Ys[0])
+    parts = [
+        jax.vmap(step)(W[lo:hi], Xs[b], Ys[b]) for b, (lo, hi, _) in enumerate(buckets)
+    ]
+    return jnp.concatenate(parts, axis=0)
+
+
+def eval_block(W, x_te, y_te, layout):
+    """Test accuracy of every row of a (rows, N) weight block."""
+    return jax.vmap(
+        lambda w: mlp_mnist.evaluate(unflatten_params(w, layout), x_te, y_te)
+    )(W)
+
+
 class _HarvestDeferred(Exception):
     """A span-boundary harvest met an in-flight message shape the dense
     planes cannot represent (possible only when max_delay_rounds exceeds
@@ -185,7 +209,8 @@ class _FateWindow:
 
 
 class VectorizedIPLSSimulation:
-    """Drop-in engine for `IPLSSimulation` under PERFECT/no-churn configs.
+    """Drop-in engine for `IPLSSimulation`: any network conditions, wire
+    dtype and churn schedule.
 
     Construction delegates to the scalar engine so the bootstrap/join
     protocol (partition transfers, donor caches, membership traffic) is
@@ -223,11 +248,15 @@ class VectorizedIPLSSimulation:
         )
         self.cfg = cfg
         # multi-round fusion: run() executes windows of `scan_rounds` rounds
-        # as one lax.scan device call each (0 = per-round calls)
+        # as one lax.scan device call each (0 = one-round windows)
         self.scan_rounds = int(getattr(cfg, "scan_rounds", 0) or 0)
         if self.scan_rounds < 0:
             raise ValueError("scan_rounds must be >= 0")
-        self._eval_cadence = max(1, int(getattr(cfg, "eval_cadence", 1) or 1))
+        # eval_cadence thins evaluation of scanned windows only: with
+        # scan_rounds=0 every round is evaluated
+        self._eval_cadence = (
+            max(1, int(getattr(cfg, "eval_cadence", 1) or 1)) if self.scan_rounds else 1
+        )
         # jitted-call counter: benchmarks report dispatches/round (the scan
         # path's whole point is driving this to 1/W)
         self.device_dispatches = 0
@@ -429,18 +458,14 @@ class VectorizedIPLSSimulation:
             self._t_inst.append(t_inst)
             self._contrib_idx.append(rows)  # ragged; padded below
         self.R_cap = R_cap
-        self._contrib_M: List[np.ndarray] = []  # (K_inst, A) 0/1 contribution matrix
         for p in range(self._period):
             idx = np.zeros((self.K_inst, R_cap), np.int32)
             msk = np.zeros((self.K_inst, R_cap), np.float32)
-            M = np.zeros((self.K_inst, A), np.float32)
             for i, row in enumerate(self._contrib_idx[p]):
                 idx[i, : len(row)] = row
                 msk[i, : len(row)] = 1.0
-                M[i, row] = 1.0
             self._contrib_idx[p] = idx
             self._contrib_mask.append(msk)
-            self._contrib_M.append(M)
 
         # ---- replica-merge order (static under PERFECT) -------------------
         # scalar merge: np.mean over [own post-agg value] + arrivals; under
@@ -492,21 +517,23 @@ class VectorizedIPLSSimulation:
         self._build_jitted()
 
     # -- jitted batched phases ---------------------------------------------
+    def _local_sgd(self):
+        """`sgd_all` bound to this fleet's batch buckets and SGD settings."""
+        cfg = self.cfg
+        return functools.partial(
+            sgd_all, buckets=self._buckets, lr=float(cfg.lr),
+            iters=int(cfg.local_iters),
+            layout_t=tuple((name, tuple(shape)) for name, shape in self.layout),
+        )
+
     def _build_jitted(self):
         cfg, layout = self.cfg, self.layout
-        A, K, N, S = self.A, self.K, self.N, self.S
-        inst_k = jnp.asarray(self._inst_k)
-        counts = jnp.asarray(
-            np.bincount(self._inst_k, minlength=K).astype(np.float32)
-        )
+        A, K, S = self.A, self.K, self.S
         offsets, sizes = self._offsets, self._sizes
         alpha = float(cfg.alpha)
-        lr, iters = float(cfg.lr), int(cfg.local_iters)
-
-        layout_t = tuple((name, tuple(shape)) for name, shape in layout)
-
+        sgd = self._local_sgd()
         use_kernel = self._use_kernel
-        # instance rows grouped by partition for the masked-matmul path
+        # instance rows grouped by partition (k-major, so contiguous)
         inst_of_k = [np.nonzero(self._inst_k == k)[0] for k in range(K)]
         x_te = jnp.asarray(self.x_test)
         y_te = jnp.asarray(self.y_test)
@@ -525,10 +552,6 @@ class VectorizedIPLSSimulation:
                     [V_all[t_inst[:, k], : sizes[k]] for k in range(K)], axis=1
                 )
 
-        # instance rows are k-major, so each partition's instances form a
-        # contiguous row range of the (K_inst, A) contribution matrix
-        inst_row0 = [int(rows[0]) if len(rows) else 0 for rows in inst_of_k]
-
         morder = jnp.asarray(self._morder_perf)
         mmask_m = jnp.asarray(self._mmask_perf)
         max_rho = int(self._morder_perf.shape[1])
@@ -537,7 +560,7 @@ class VectorizedIPLSSimulation:
         )
         R_cap = int(self.R_cap)
 
-        def agg_merge(V_merged, eps, W, W2, contrib_idx, contrib_mask, contrib_M):
+        def agg_merge(V_merged, eps, W, W2, contrib_idx, contrib_mask):
             """Aggregation + replica consensus, given the pre/post local-SGD
             weight matrices. The contributor gather + sequential masked sum
             reduces each instance's deltas in the scalar oracle's pending
@@ -590,66 +613,27 @@ class VectorizedIPLSSimulation:
 
         def eval_rows(V_pre, V_merged_new, t_eval):
             # evaluate ONLY the sub-sampled agents: their assembled rows are
-            # a few MB, so the full (A, N) matrix never leaves this call
-            W_eval = build_W(V_pre, V_merged_new, t_eval, E)
-            return jax.vmap(
-                lambda w: mlp_mnist.evaluate(unflatten_params(w, layout), x_te, y_te)
-            )(W_eval)
+            # a few MB, so the full (A, N) matrix never leaves the program
+            return eval_block(build_W(V_pre, V_merged_new, t_eval, E), x_te, y_te, layout)
 
-        # telemetry: a python-bool trace-time gate — False leaves every
-        # jitted program EXACTLY as before (no extra outputs in the jaxpr);
-        # True adds one (2,) aux output per round with the f32 norm metrics
+        # telemetry: a python-bool trace-time gate — False adds nothing to
+        # the jitted program (no extra outputs in the jaxpr); True adds one
+        # (2,) aux output per round with the f32 norm metrics
         tel = self.recorder is not None
 
-        def round_core(V_merged, eps, W, W2, contrib_idx, contrib_mask, contrib_M, t_eval):
-            V_pre, V_merged_new, eps_new = agg_merge(
-                V_merged, eps, W, W2, contrib_idx, contrib_mask, contrib_M
-            )
-            out = (V_pre, V_merged_new, eps_new, eval_rows(V_pre, V_merged_new, t_eval))
-            if tel:
-                out = out + (metric_pair(W - W2, V_merged_new),)
-            return out
-
-        buckets = self._buckets
-
-        def sgd_all(W, Xs, Ys):
-            """All agents' local SGD on the (A, N) weight matrix; Xs/Ys are
-            per-bucket stacked batches (a single bucket unless array_split
-            handed out two shard sizes). Flat-space SGD, bit-identical to the
-            tree scan (same GEMMs, same update order), saves the per-agent
-            tree<->vector passes."""
-            step = lambda w, x, y: mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
-            if len(buckets) == 1:
-                return jax.vmap(step)(W, Xs[0], Ys[0])
-            parts = [
-                jax.vmap(step)(W[lo:hi], Xs[b], Ys[b])
-                for b, (lo, hi, _) in enumerate(buckets)
-            ]
-            return jnp.concatenate(parts, axis=0)
-
-        def fused_round(V_pre, V_merged, eps, x_tab, y_tab, I, t_prev, contrib_idx, contrib_mask, contrib_M, t_eval):
-            """One whole training round in a single device call: rebuild all
-            agents' weights, gather their batches from the device-resident
-            training set, run every agent's local SGD, aggregate every
-            partition instance, merge replicas, evaluate."""
-            W = build_W(V_pre, V_merged, t_prev, A)
-            W2 = sgd_all(W, *gather_batches(x_tab, y_tab, I))
-            return round_core(V_merged, eps, W, W2, contrib_idx, contrib_mask, contrib_M, t_eval)
-
         def make_scan(gate_eval: bool):
-            """The multi-round fused path: a window of W rounds as ONE
+            """The PERFECT device program: a window of W rounds as ONE
             device call, `lax.scan` over per-round xs (batch row indices +
             routing tables), carry = the small value tables (V_pre,
             V_merged, eps); the training set is an argument, gathered from
-            in the body. The scanned body is exactly `fused_round`'s math, so
-            any W produces the same trajectory as W unscanned calls."""
+            in the body. A per-round call is a one-round window."""
 
             def body(x_tab, y_tab, carry, xs):
                 V_pre, V_merged, eps = carry
-                Ir, t_prev, idx, mask, M, t_eval, de = xs
+                Ir, t_prev, idx, mask, t_eval, de = xs
                 W = build_W(V_pre, V_merged, t_prev, A)
-                W2 = sgd_all(W, *gather_batches(x_tab, y_tab, Ir))
-                V_pre2, V_m2, eps2 = agg_merge(V_merged, eps, W, W2, idx, mask, M)
+                W2 = sgd(W, *gather_batches(x_tab, y_tab, Ir))
+                V_pre2, V_m2, eps2 = agg_merge(V_merged, eps, W, W2, idx, mask)
                 if gate_eval:
                     accs = jax.lax.cond(
                         de,
@@ -670,26 +654,14 @@ class VectorizedIPLSSimulation:
 
             return jax.jit(scan_window, donate_argnums=(0, 1, 2))
 
-        self._fused_round = jax.jit(fused_round, donate_argnums=(0, 1, 2))
         self._scan_window_j = make_scan(self._eval_cadence > 1)
-        # routing tables cycle with the phase; upload to device once
-        self._phase_tables = [
-            (
-                jnp.asarray(self._contrib_idx[p]),
-                jnp.asarray(self._contrib_mask[p]),
-                jnp.asarray(self._contrib_M[p]),
-                jnp.asarray(self._t_inst[p]),
-                jnp.asarray(self._t_inst[p][self._eval_idx]),
-            )
-            for p in range(self._period)
-        ]
 
     # ===================== LOSSY (mask-stream) path ========================
     def _init_lossy(self, seed_sim):
         """State for the lossy-network batched path.
 
-        The protocol's per-parameter math stays in a handful of jitted
-        batched calls per round; what loss/delay add is a tiny host-side
+        The protocol's per-parameter math stays in one scanned device
+        program a window; what loss/delay add is a tiny host-side
         control plane (integer/boolean numpy over (A, K)): the keyed fate
         stream shared with the scalar pubsub, the fetch warm-up state
         machine, and event queues for in-flight serves/arrivals/merges/
@@ -1382,13 +1354,11 @@ class VectorizedIPLSSimulation:
         return self._live_ids()
 
     def _build_jitted_lossy(self):
-        cfg, layout = self.cfg, self.layout
+        layout = self.layout
         A, K, N, S, K_inst = self.A, self.K, self.N, self.S, self.K_inst
         Lu, HD = self._Lu, self._HD
         sizes, offsets = self._sizes, self._offsets
-        alpha = float(cfg.alpha)
-        lr, iters = float(cfg.lr), int(cfg.local_iters)
-        layout_t = tuple((name, tuple(shape)) for name, shape in layout)
+        sgd = self._local_sgd()
         LA = (Lu + 1) * A
         use_kernel = self._use_kernel
         int8 = self._int8
@@ -1412,7 +1382,6 @@ class VectorizedIPLSSimulation:
         MAIL = 0 if self._V_mail is None else int(self._V_mail.shape[0])
         V_mail_j = jnp.asarray(self._V_mail) if MAIL else None
         inst_of_k = [np.nonzero(self._inst_k == k)[0] for k in range(K)]
-        inst_row0 = [int(rows[0]) if len(rows) else 0 for rows in inst_of_k]
         x_te = jnp.asarray(self.x_test)
         y_te = jnp.asarray(self.y_test)
 
@@ -1609,12 +1578,9 @@ class VectorizedIPLSSimulation:
 
         def eval_lossy(V_new, C2):
             # evaluate the sub-sampled agents on end-of-round state
-            W_eval = build_W(V_new, C2, plan_eval)
-            return jax.vmap(
-                lambda w: mlp_mnist.evaluate(unflatten_params(w, layout), x_te, y_te)
-            )(W_eval)
+            return eval_block(build_W(V_new, C2, plan_eval), x_te, y_te, layout)
 
-        # telemetry: python-bool trace-time gate — False keeps every jitted
+        # telemetry: python-bool trace-time gate — False keeps the jitted
         # program's jaxpr unchanged; True adds the (2,) f32 norm-metric aux
         # output (deltas RAW pre-quantize, values the authoritative plane)
         tel = self.recorder is not None
@@ -1626,46 +1592,22 @@ class VectorizedIPLSSimulation:
                 return D_act
             return jnp.zeros((A, N), jnp.float32).at[act_idx_j].set(D_act)
 
-        def core(V, C0, D_act, ring, Vagg_hist, Vstart_new, E,
-                 msrc, eps, mmask, merge_cnt, c2_mask, c2_src, kidx, kmask):
-            V_new, C2, ring_new, Vagg_hist_new, E_new = core_main(
-                V, C0, expand_rows(D_act), ring, Vagg_hist, Vstart_new, E,
-                msrc, eps, mmask, merge_cnt, c2_mask, c2_src, kidx, kmask,
-            )
-            accs = eval_lossy(V_new, C2)
-            out = (V_new, C2, ring_new, Vagg_hist_new, E_new, accs)
-            if tel:
-                # delta metrics over the TRAINED rows only — the scalar
-                # emission stacks exactly the active agents' deltas
-                out = out + (metric_pair(D_act, V_new),)
-            return out
-
-        buckets = self._buckets
         E = len(self._eval_idx)
 
-        def sgd_all(W, Xs, Ys):
-            step = lambda w, x, y: mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
-            if len(buckets) == 1:
-                return jax.vmap(step)(W, Xs[0], Ys[0])
-            parts = [
-                jax.vmap(step)(W[lo:hi], Xs[b], Ys[b])
-                for b, (lo, hi, _) in enumerate(buckets)
-            ]
-            return jnp.concatenate(parts, axis=0)
-
         def make_scan(gate_eval: bool):
-            """Multi-round fused LOSSY path: fold pre / SGD / core into a
-            single scanned body, one device call per W-round window. The
-            carry is the fixed-shape device state (weights plane, cache
-            plane, delta ring, value-history rings); the host control
-            plane's per-round dense tensors ride as scan xs."""
+            """The LOSSY device program: pre / SGD / core in one scanned
+            body, one device call per W-round window (a per-round call is a
+            one-round window). The carry is the fixed-shape device state
+            (weights plane, cache plane, delta ring, value-history rings);
+            the host control plane's per-round dense tensors ride as scan
+            xs."""
 
             def body(x_tab, y_tab, carry, xs):
                 V, C, ring, Vagg_hist, Vstart_hist, Eres = carry
                 (Ir, c0_mask, c0_src, msrc, eps, mmask, cnt,
                  c2_mask, c2_src, kidx, kmask, de) = xs
                 Vstart_new, C0, W = pre(V, C, Vstart_hist, Vagg_hist, c0_mask, c0_src)
-                W2 = sgd_all(W, *gather_batches(x_tab, y_tab, Ir))
+                W2 = sgd(W, *gather_batches(x_tab, y_tab, Ir))
                 D_act = W - W2
                 V_new, C2, ring_new, Vagg_hist_new, E_new = core_main(
                     V, C0, expand_rows(D_act), ring, Vagg_hist, Vstart_new, Eres,
@@ -1681,6 +1623,8 @@ class VectorizedIPLSSimulation:
                     accs = eval_lossy(V_new, C2)
                 carry_new = (V_new, C2, ring_new, Vagg_hist_new, Vstart_new, E_new)
                 if tel:
+                    # delta metrics over the TRAINED rows only — the scalar
+                    # emission stacks exactly the active agents' deltas
                     return carry_new, (accs, metric_pair(D_act, V_new))
                 return carry_new, accs
 
@@ -1692,12 +1636,7 @@ class VectorizedIPLSSimulation:
 
             return jax.jit(scan_window, donate_argnums=(0, 1, 2, 3, 4, 5))
 
-        self._lossy_pre_j = jax.jit(pre, donate_argnums=(1,))
-        self._lossy_core_j = jax.jit(core, donate_argnums=(0, 1, 3, 4, 6))
         self._scan_window_j = make_scan(self._eval_cadence > 1)
-        self._sgd_deltas_j = jax.jit(
-            lambda W, x_tab, y_tab, I: W - sgd_all(W, *gather_batches(x_tab, y_tab, I))
-        )
 
     def _push_cache_event(self, deliver_ctr, send_ctr, a, k, kind, src_round, inst):
         """Schedule a cache write for the round whose drain sees the message.
@@ -1714,26 +1653,20 @@ class VectorizedIPLSSimulation:
         )
         self._seq += 1
 
-    def _control_round(self, r0: int, W: int = 1, wf: "_FateWindow | None" = None) -> list:
+    def _control_round(self, r0: int, W: int, wf: _FateWindow) -> list:
         """Rounds r0 .. r0+W-1 of the host-side control plane, in order: one
         call a window, so that a scan window runs its W control rounds up
         front and stacks their dense tensors as `lax.scan` xs."""
         return [self._control_one_round(r0 + w, wf) for w in range(W)]
 
-    def _control_one_round(self, rnd: int, wf: "_FateWindow | None" = None) -> dict:
+    def _control_one_round(self, rnd: int, wf: _FateWindow) -> dict:
         """One round of the host-side control plane: fate draws, queue-ring
         drains, fetch warm-up state machine, traffic counters. Pure
         integer/boolean numpy over the fixed-shape event space — no device
         data. Returns the per-round control tensors plus (msgs, drops,
         nbytes), which are exactly the scalar pubsub's counters for the
         round by construction."""
-        from repro.fl.rounds import (
-            CH_FETCH,
-            CH_FETCH_REPLY,
-            CH_REPLICA,
-            CH_UPDATE,
-            CH_UPDATE_REPLY,
-        )
+        from repro.fl.rounds import CH_FETCH_REPLY, CH_UPDATE_REPLY
 
         t = self._t
         TICKS = self._ticks
@@ -1741,7 +1674,6 @@ class VectorizedIPLSSimulation:
         rec = self.recorder
         A, K, K_inst = self.A, self.K, self.K_inst
         Lu, HD = self._Lu, self._HD
-        sizes = self._sizes
         owner = self._owner_col
         rho = self._rho
         act = self._act
@@ -1773,11 +1705,7 @@ class VectorizedIPLSSimulation:
         need = act_col & (~owner) & (~self._has_cache) & has_tgt
         n_need = int(need.sum())
         if n_need:
-            de, dl = (
-                wf.slice("fetch", t)
-                if wf
-                else f.draw(CH_FETCH, t, self._ids_col, k_row)
-            )
+            de, dl = wf.slice("fetch", t)
             lost = need & ~de
             offl = need & de & ~tgt_act
             live = need & de & tgt_act
@@ -1821,11 +1749,7 @@ class VectorizedIPLSSimulation:
             rec.on_delays(rnd, sv_delays)
 
         # ---- phase 2: UpdateModel sends -----------------------------------
-        de_u, dl_u = (
-            wf.slice("update", t)
-            if wf
-            else f.draw(CH_UPDATE, t, self._ids_col, k_row)
-        )
+        de_u, dl_u = wf.slice("update", t)
         send_u = self._upd_send_mask
         msgs += self._upd_msgs
         nbytes += self._upd_bytes
@@ -1908,13 +1832,7 @@ class VectorizedIPLSSimulation:
         if len(self._rep_src):
             msgs += self._pub_msgs
             nbytes += self._pub_bytes
-            de_p, dl_p = (
-                wf.slice("replica", t)
-                if wf
-                else f.draw(
-                    CH_REPLICA, t, self._rep_src_agent, self._rep_k, self._rep_dst_agent
-                )
-            )
+            de_p, dl_p = wf.slice("replica", t)
             lost_p = ~de_p
             offl_p = de_p & ~self._rep_dst_act
             live_p = de_p & self._rep_dst_act
@@ -2046,62 +1964,6 @@ class VectorizedIPLSSimulation:
             ctl["eps64"] = self._eps64.copy()
         return ctl
 
-    def _run_round_lossy(self, rnd: int) -> dict:
-        pt = self._pt
-        with pt.phase("control"):
-            ctl, = self._control_round(rnd)
-
-        # ---- device calls -------------------------------------------------
-        with pt.phase("batches"):
-            idx = self._draw_batches(1)
-        with pt.phase("stage"):
-            Is = self._stage_batches(a[0] for a in idx)
-            c = {key: self._put(ctl[key]) for key in (
-                "c0_mask", "c0_src", "msrc", "eps", "mmask", "cnt",
-                "c2_mask", "c2_src", "kidx", "kmask",
-            )}
-        with pt.phase("device_pre"):
-            Vstart_new, C0, W = self._lossy_pre_j(
-                self._Vl, self._C, self._Vstart_hist, self._Vagg_hist,
-                c["c0_mask"], c["c0_src"],
-            )
-            if pt.sync:
-                jax.block_until_ready(W)
-        with pt.phase("device_sgd"):
-            D_now = self._sgd_deltas_j(W, self._x_tab, self._y_tab, Is)
-            if pt.sync:
-                jax.block_until_ready(D_now)
-        with pt.phase("device_core"):
-            out = self._lossy_core_j(
-                self._Vl, C0, D_now, self._ring, self._Vagg_hist,
-                Vstart_new, self._E, c["msrc"], c["eps"], c["mmask"], c["cnt"],
-                c["c2_mask"], c["c2_src"], c["kidx"], c["kmask"],
-            )
-            if pt.sync:
-                jax.block_until_ready(out)
-        met = None
-        if self.recorder is not None:
-            (
-                self._Vl, self._C, self._ring, self._Vagg_hist,
-                self._E, accs, met,
-            ) = out
-        else:
-            (
-                self._Vl, self._C, self._ring, self._Vagg_hist,
-                self._E, accs,
-            ) = out
-        self._Vstart_hist = Vstart_new
-        self.device_dispatches += 3
-
-        self.messages_sent += ctl["msgs"]
-        self.messages_dropped += ctl["drops"]
-        self._bytes_total += ctl["nbytes"]
-        metrics = self._metrics_entry(rnd, np.asarray(accs, np.float32))
-        self.history.append(metrics)
-        if self.recorder is not None:
-            self._emit_row(rnd, ctl["r_vec"], ctl["eps64"], met)
-        return metrics
-
     def _run_window_lossy(self, r0: int, W: int) -> None:
         """W LOSSY rounds as one lax.scan device call: run the host control
         plane W times up front (windowed fate draws where the keys are
@@ -2225,43 +2087,18 @@ class VectorizedIPLSSimulation:
         return met
 
     def run_round(self, rnd: int) -> dict:
+        """One round: a one-round window of the path's device program
+        (membership-event rounds, and rounds no fused span can host, replay
+        on the scalar oracle). Returns the round's history entry."""
         if self._lossy:
             if rnd in self._replay_set:
                 return self._scalar_round(rnd)
             if not self._on_device and not self._scalar_to_device(rnd):
                 return self._scalar_round(rnd)
-            return self._run_round_lossy(rnd)
-        pt = self._pt
-        with pt.phase("batches"):
-            idx = self._draw_batches(1)
-        with pt.phase("stage"):
-            Is = self._stage_batches(a[0] for a in idx)
-        p = rnd % self._period
-        p_prev = self._last_phase
-        idx, mask, M, t_inst, t_eval = self._phase_tables[p]
-        t_prev = self._phase_tables[p_prev][3]
-        with pt.phase("device_round"):
-            out = self._fused_round(
-                self._V_pre, self._V_merged, self._eps, self._x_tab, self._y_tab,
-                Is, t_prev, idx, mask, M, t_eval,
-            )
-            if pt.sync:
-                jax.block_until_ready(out)
-        met = None
-        if self.recorder is not None:
-            self._V_pre, self._V_merged, self._eps, accs, met = out
+            self._run_window_lossy(rnd, 1)
         else:
-            self._V_pre, self._V_merged, self._eps, accs = out
-        self.device_dispatches += 1
-        self._last_phase = p
-        accs = np.asarray(accs, np.float32)
-
-        self._perfect_traffic(rnd)
-        metrics = self._metrics_entry(rnd, accs)
-        self.history.append(metrics)
-        if self.recorder is not None:
-            self._emit_perfect(rnd, met)
-        return metrics
+            self._run_window_perfect(rnd, 1)
+        return self.history[-1]
 
     def _perfect_traffic(self, rnd: int) -> None:
         self._bytes_total += self._round_bytes + (
@@ -2277,7 +2114,8 @@ class VectorizedIPLSSimulation:
         """The engine's single telemetry emission site: one schema-ordered
         finish_round per round, from the device aux metrics + control-plane
         snapshots. Shapes/float paths mirror the scalar engine exactly
-        (byte-identical rows; tests/test_telemetry.py)."""
+        (rows byte-identical but for the f32 norms, which agree within 2
+        ULP; tests/test_telemetry.py)."""
         m = np.asarray(met, np.float32)
         self.recorder.finish_round(
             round=rnd,
@@ -2321,8 +2159,8 @@ class VectorizedIPLSSimulation:
         self._emit_row(rnd, r, self._tel_eps64, met)
 
     def _do_eval(self, rnd: int) -> bool:
-        """Scanned-mode eval gate: every `eval_cadence`-th round plus the
-        final round of the run."""
+        """Eval gate: every `eval_cadence`-th round plus the final round of
+        the run (every round when scan_rounds=0, see `_eval_cadence`)."""
         return (rnd + 1) % self._eval_cadence == 0 or rnd == self.cfg.rounds - 1
 
     def _metrics_entry(self, rnd: int, accs: np.ndarray) -> dict:
@@ -2356,14 +2194,13 @@ class VectorizedIPLSSimulation:
         with pt.phase("stage"):
             Is = self._stage_batches(idx)
             prev = self._last_phase
-            t_prev_l, idx_l, mask_l, M_l, t_eval_l, de_l = [], [], [], [], [], []
+            t_prev_l, idx_l, mask_l, t_eval_l, de_l = [], [], [], [], []
             for w in range(W):
                 rnd = r0 + w
                 p = rnd % self._period
                 t_prev_l.append(self._t_inst[prev])
                 idx_l.append(self._contrib_idx[p])
                 mask_l.append(self._contrib_mask[p])
-                M_l.append(self._contrib_M[p])
                 t_eval_l.append(self._t_inst[p][self._eval_idx])
                 de_l.append(self._do_eval(rnd))
                 prev = p
@@ -2371,8 +2208,8 @@ class VectorizedIPLSSimulation:
             xs_all = (
                 Is,
                 put(np.stack(t_prev_l)), put(np.stack(idx_l)),
-                put(np.stack(mask_l)), put(np.stack(M_l)),
-                put(np.stack(t_eval_l)), put(np.asarray(de_l, bool)),
+                put(np.stack(mask_l)), put(np.stack(t_eval_l)),
+                put(np.asarray(de_l, bool)),
             )
         with pt.phase("device_window"):
             out = self._scan_window_j(
@@ -2426,24 +2263,20 @@ class VectorizedIPLSSimulation:
         return self.history[n0:]
 
     def run(self) -> List[dict]:
-        W = self.scan_rounds
+        W = self.scan_rounds or 1
         R = self.cfg.rounds
-        if W:
-            rnd = 0
-            while rnd < R:
-                if rnd in self._replay_set:
-                    # membership event: replay this round on the oracle,
-                    # then resume fused windows after the re-snapshot
-                    self.run_round(rnd)
-                    rnd += 1
-                    continue
-                nxt = next((r for r in self._replay if r > rnd), R)
-                step = min(W, nxt - rnd)
-                self.run_window(rnd, step)
-                rnd += step
-        else:
-            for rnd in range(R):
+        rnd = 0
+        while rnd < R:
+            if rnd in self._replay_set:
+                # membership event: replay this round on the oracle, then
+                # resume fused windows after the re-snapshot
                 self.run_round(rnd)
+                rnd += 1
+                continue
+            nxt = next((r for r in self._replay if r > rnd), R)
+            step = min(W, nxt - rnd)
+            self.run_window(rnd, step)
+            rnd += step
         return self.history
 
     # -- introspection (tests / benchmarks) ---------------------------------

@@ -12,7 +12,8 @@ Both end with ONE ``finish_round(...)`` call per round per engine — the
 emission site the PR04 analysis rule pins to the schema — which folds the
 accumulated traffic with the round's state metrics into an ordered row.
 Rows and their JSONL serialization are byte-for-byte identical across
-engines under identical configs (tests/test_telemetry.py).
+engines under identical configs, except the two float32 norm columns,
+which agree within 2 ULP (tests/test_telemetry.py).
 """
 from __future__ import annotations
 

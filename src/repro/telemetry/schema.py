@@ -1,9 +1,10 @@
 """The shared per-round metric schema — the single source of truth.
 
-Every engine (scalar pubsub oracle, vectorized per-round, multi-round
-scanned) emits EXACTLY these keys per round, in this order, with values
-that agree byte-for-byte across engines under identical configs (asserted
-in tests/test_telemetry.py). The static-analysis rule PR04
+Every engine (scalar pubsub oracle, vectorized at any window size) emits
+EXACTLY these keys per round, in this order, with values that agree
+byte-for-byte across engines under identical configs, except the float32
+norm reductions ``delta_normsq``/``value_normsq``, which agree within 2 ULP
+(asserted in tests/test_telemetry.py; see ``telemetry/device.py``). The static-analysis rule PR04
 (``repro.analysis.rules_protocol.MetricSchemaSymmetry``) checks that every
 ``finish_round`` emission site passes keys from this schema and that the
 scalar and vectorized emitters stay mirrored; its hardcoded copy of these

@@ -10,7 +10,7 @@ class VectorizedEngine:
         self.messages_dropped = 0
         self._bytes_total = 0
 
-    def _run_round_lossy(self, ctl):
+    def _run_window_lossy(self, ctl):
         self.messages_sent += ctl["msgs"]
         self.messages_dropped += ctl["drops"]
         self._bytes_total += ctl["nbytes"]

@@ -1,12 +1,12 @@
 """Multi-round fused scan engine correctness.
 
 ``SimConfig(scan_rounds=W)`` folds W rounds into a single ``lax.scan`` device
-call. The fused path must be *W-invariant*: any window size (including
-partial tail windows) produces bit-for-bit the same per-round history and
-the same final weights as the unscanned vectorized engine — which in turn
-matches the scalar pubsub oracle to float tolerance with exact traffic
-counters. Eval can additionally be thinned to a cadence without perturbing
-the training trajectory.
+call; ``scan_rounds=0`` runs one-round windows of the same program. The fused
+path must be *W-invariant*: any window size (including partial tail
+windows) produces the same per-round history and final weights as
+one-round windows — which in turn match the scalar pubsub oracle to float
+tolerance with exact traffic counters. Eval can additionally be thinned to a
+cadence without perturbing the training trajectory.
 """
 
 import hashlib
@@ -18,8 +18,8 @@ from repro.data import iid_split, synth_mnist
 from repro.fl import SimConfig, make_simulation
 from repro.p2p.network import LOSSY, NetworkConditions
 
-# scanned vs unscanned is the same arithmetic in a different dispatch
-# grouping: only scheduling noise separates them (PR-2 observed ~3e-8)
+# windows of different sizes are the same arithmetic in a different dispatch
+# grouping: only scheduling noise separates them (~3e-8 observed)
 ATOL_SCAN = 3e-7
 # vectorized vs scalar re-associates batched reductions: PR-2 tolerance
 ATOL_ORACLE = 1e-4
@@ -65,11 +65,12 @@ NETS = [
 
 @pytest.mark.parametrize("net", NETS)
 def test_scan_w_invariance(data, net):
-    """unscanned == scan_rounds=1 == scan_rounds=4: identical weights,
-    metrics, and counters round-by-round (W only regroups dispatches)."""
+    """scan_rounds=1 == 2 == 4: identical weights, metrics, and counters
+    round-by-round (W only regroups dispatches; scan_rounds=0 is W=1 by
+    construction)."""
     kw = dict(num_agents=5, num_partitions=8, pi=2, rho=2, **net)
-    sim_u, hist_u = _run(data, "vectorized", 0, **kw)
-    for W in (1, 4):
+    sim_u, hist_u = _run(data, "vectorized", 1, **kw)
+    for W in (2, 4):
         sim_w, hist_w = _run(data, "vectorized", W, **kw)
         _assert_same_vectorized(sim_u, hist_u, sim_w, hist_w)
 
@@ -96,7 +97,7 @@ def test_scan8_matches_scalar_oracle(data, net):
 @pytest.mark.parametrize("net", NETS)
 def test_scan_partial_tail_window(data, net):
     """rounds not divisible by scan_rounds: the tail window is shorter and
-    must still agree with the unscanned engine."""
+    must still agree with one-round windows."""
     kw = dict(num_agents=4, num_partitions=6, pi=2, rho=2, rounds=7, **net)
     sim_u, hist_u = _run(data, "vectorized", 0, **kw)
     sim_w, hist_w = _run(data, "vectorized", 4, **kw)  # windows of 4 + 3
@@ -138,6 +139,17 @@ def test_eval_cadence_thins_eval_without_perturbing_training(data, net):
         assert np.isfinite(mc["acc_mean"])  # skipped rounds carry last eval
 
 
+@pytest.mark.parametrize("net", NETS)
+def test_eval_cadence_ignored_without_scan(data, net):
+    """eval_cadence thins only scanned windows: scan_rounds=0 evaluates
+    every round, so its history equals the eval_cadence=1 run."""
+    kw = dict(num_agents=5, num_partitions=8, pi=2, rho=2, **net)
+    sim_1, hist_1 = _run(data, "vectorized", 0, **kw)
+    sim_3, hist_3 = _run(data, "vectorized", 0, cadence=3, **kw)
+    assert hist_3 == hist_1
+    np.testing.assert_array_equal(sim_3.agent_weights(), sim_1.agent_weights())
+
+
 def test_scan_rounds_rejected_for_negative():
     cfg = SimConfig(num_agents=4, rounds=2, engine="vectorized", scan_rounds=-1)
     x = np.zeros((40, 784), np.float32)
@@ -160,9 +172,10 @@ def test_scalar_engine_ignores_scan_rounds(data):
 
 # Output digests of the fused engine, pinned on the CPU before the window's
 # batches moved from host-staged images to a device-resident training set
-# gathered by staged row indices. The move changes where the copy happens,
-# not what SGD sees: weights, accuracies and traffic counters stay equal to
-# the bit, scanned (W=4) and per round (W=0).
+# gathered by staged row indices, and kept when the per-round programs gave
+# way to one-round windows (scan 0). Neither change moves what SGD sees:
+# weights, accuracies and traffic counters stay equal to the bit, in windows
+# of 4 rounds and of one.
 def _join_shard(agent_id):
     # a joiner's own 100-row shard: one the training table lacks, and a
     # second batch bucket (100 rows < batch size 128)
@@ -228,7 +241,7 @@ def test_staged_bytes_counts_indices_not_images(data, net):
         per_round = sum(
             t.nbytes for t in (
                 sim._t_inst[0], sim._contrib_idx[0], sim._contrib_mask[0],
-                sim._contrib_M[0], sim._t_inst[0][sim._eval_idx],
+                sim._t_inst[0][sim._eval_idx],
             )
         ) + 1
         assert sim.staged_bytes == index_bytes + W * per_round
