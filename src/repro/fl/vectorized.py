@@ -8,8 +8,8 @@ that batches the three round phases:
 
   1. local SGD for all A agents at once — agents' flat weights assembled
      into an (A, N) matrix inside the call and trained with `jax.vmap` over
-     `mlp_mnist.sgd_steps_flat` (flat-space SGD, bit-identical to the tree
-     scan of `sgd_steps`);
+     `mlp_mnist.sgd_steps_flat` (each agent's row unflattened once, the tree
+     scan of `sgd_steps` run on it, the result flattened once);
   2. aggregation of every (partition, replica-slot) instance: on TPU one
      partition-batched Pallas launch (`kernels/ipls_aggregate`) with deltas
      laid out (K_inst, R, S) + a per-instance (mask, r, eps) table; on
@@ -152,9 +152,9 @@ def gather_batches(x_tab, y_tab, idx):
 def sgd_all(W, Xs, Ys, *, buckets, lr, iters, layout_t):
     """All rows' local SGD on the (rows, N) weight matrix; Xs/Ys are
     per-bucket stacked batches (a single bucket unless array_split handed
-    out two shard sizes). Flat-space SGD, bit-identical to the tree scan
-    (same GEMMs, same update order), saves the per-agent tree<->vector
-    passes."""
+    out two shard sizes). Each row is unflattened once, trained by the tree
+    scan and flattened once: the tree<->vector passes are paid once a call,
+    not once a step, bit-identical to the scalar engine's tree scan."""
     step = lambda w, x, y: mlp_mnist.sgd_steps_flat(w, x, y, lr, iters, layout_t)
     if len(buckets) == 1:
         return jax.vmap(step)(W, Xs[0], Ys[0])

@@ -62,19 +62,29 @@ def sgd_steps(params, x, y, lr: float, num_iters: int):
 
 @partial(jax.jit, static_argnums=(4, 5))
 def sgd_steps_flat(w_flat, x, y, lr: float, num_iters: int, layout):
-    """`sgd_steps` on the FLAT weight vector: the loss unflattens inside, so
-    the gradient arrives flat (the slice/reshape transpose fuses into the
-    backward) and callers never pay the tree->vector->tree round trips. The
-    vectorized round engine vmaps this over all agents; `layout` is the
-    (hashable) flatten layout from `core.partition.flatten_params`."""
+    """`sgd_steps` on the FLAT weight vector: unflatten once, carry the
+    parameter tree through the `num_iters` steps, flatten once in `layout`
+    order. The flat<->tree re-layouts are paid once a call, not once a step,
+    and the result is bit-identical to the tree scan. The vectorized round
+    engine vmaps this over all agents; `layout` is the (hashable) flatten
+    layout from `core.partition.flatten_params`."""
     from repro.core.partition import unflatten_params
 
-    def body(w, _):
-        g = jax.grad(lambda q: loss_and_acc(unflatten_params(q, layout), x, y)[0])(w)
-        return w - lr * g, None
+    params = sgd_steps(unflatten_params(w_flat, layout), x, y, lr, num_iters)
+    return _flatten_in_layout_order(params, layout)
 
-    w2, _ = jax.lax.scan(body, w_flat, None, length=num_iters)
-    return w2
+
+def _flatten_in_layout_order(params, layout) -> jax.Array:
+    """Traced inverse of `unflatten_params`: the leaves named by `layout`,
+    raveled and concatenated in its order."""
+
+    def leaf(name):
+        node = params
+        for part in name.split("/"):
+            node = node[part]
+        return node.reshape(-1)
+
+    return jnp.concatenate([leaf(name) for name, _ in layout])
 
 
 @jax.jit
